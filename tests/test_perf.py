@@ -774,3 +774,33 @@ class TestAcceptanceDrill:
         new_reg.write_text(json.dumps(regressed))
         assert perfcheck.main([str(old), str(new_same)]) == 0
         assert perfcheck.main([str(old), str(new_reg)]) == 1
+
+
+class TestCompileCache:
+    """trivy_tpu.compile_cache: JAX_COMPILATION_CACHE_DIR wins and
+    nothing is set in code; without it, the fixed in-checkout dir."""
+
+    @pytest.fixture
+    def cache_config(self):
+        import jax
+        prev = jax.config.jax_compilation_cache_dir
+        yield jax.config
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+    def test_env_var_is_honoured(self, cache_config, monkeypatch,
+                                 tmp_path):
+        from trivy_tpu import compile_cache
+        before = cache_config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable() == str(tmp_path)
+        assert cache_config.jax_compilation_cache_dir == before
+
+    def test_fixed_checkout_dir_when_unset(self, cache_config,
+                                           monkeypatch):
+        from trivy_tpu import compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(
+            __file__)))
+        want = os.path.join(root, ".jax_cache")
+        assert compile_cache.enable() == want
+        assert cache_config.jax_compilation_cache_dir == want

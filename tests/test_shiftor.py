@@ -22,6 +22,25 @@ GHP = "ghp_" + "a" * 36
 AWS_KEY = "AKIA" + "Z" * 16
 
 
+@pytest.fixture(autouse=True)
+def _clean_guard():
+    """Per-test guard: the path-counter deltas below assume the device
+    path is allowed, and an earlier test file in the same xdist worker
+    may have left the shared breaker open or the watchdog deadline at
+    a few ms (shorter than a CPU jnp-scan compile)."""
+    from trivy_tpu.resilience import GUARD
+    from trivy_tpu.resilience.failpoints import FAILPOINTS
+
+    def clean():
+        FAILPOINTS.configure("")
+        GUARD.reset_for_tests()
+        GUARD.configure(dispatch_timeout_s=120.0, fail_threshold=3,
+                        reset_timeout_s=5.0)
+    clean()
+    yield
+    clean()
+
+
 @pytest.fixture(scope="module")
 def bank():
     return SecretScanner(use_device=False)._bank
@@ -267,7 +286,7 @@ class TestEngineParity:
         def boom(files):
             called["device"] = True
             raise AssertionError("device path on a small batch")
-        monkeypatch.setattr(s, "_keyword_masks_device", boom)
+        monkeypatch.setattr(s, "_pack", boom)
         out = s._keyword_masks([b"tiny AKIA file"])
         assert not called["device"]
         assert out[0]  # aws rule keyword present
@@ -354,39 +373,27 @@ class TestCoalesceAndPaths:
         assert prec["type"] == "histogram"
         assert any(v > 0 for _, _, v in prec["samples"])
 
-    def test_pallas_downgrade_is_signalled(self, bank, monkeypatch):
-        """A pallas compile failure must not silently cost every later
-        scan its kernel: the downgrade logs, flips _pallas_ok, and the
-        launch is still served (path=jnp), bit-identical."""
-        import logging
-
+    def test_pallas_compile_failure_raises(self, bank, monkeypatch):
+        """A kernel the compiler refuses is a bug, not a device fault:
+        the scan raises, the detect breaker is not charged, and no
+        prefilter path is counted as having served it."""
         import trivy_tpu.secret.engine as eng
-        from trivy_tpu.log import get as get_logger
+        from trivy_tpu.resilience import GUARD
         monkeypatch.setattr(eng, "_tpu_backend", lambda: True)
         s = SecretScanner(small_batch_bytes=0)
 
-        def broken(piece):
+        def refuse(shape):
             raise RuntimeError("mosaic says no")
-        monkeypatch.setattr(s, "_pallas_scan", broken)
-        files = _hostile_files(bank)
-        records = []
-
-        class Tap(logging.Handler):
-            def emit(self, record):
-                records.append(record)
-        tap = Tap()
-        logger = get_logger("secret")
-        logger.addHandler(tap)
-        try:
-            masks, path = s._keyword_masks_device(
-                [c for _, c in files])
-        finally:
-            logger.removeHandler(tap)
-        assert s._pallas_ok is False
-        assert path == "jnp"
-        assert masks == s._keyword_masks_host([c for _, c in files])
-        assert any("downgrades the secret prefilter" in r.getMessage()
-                   for r in records)
+        monkeypatch.setattr(s, "_pallas_kernel", refuse)
+        paths = ("pallas", "jnp", "host")
+        before = [METRICS.get("trivy_tpu_secret_prefilter_path_total",
+                              path=p) for p in paths]
+        failures = GUARD.breaker._failures
+        with pytest.raises(RuntimeError, match="mosaic says no"):
+            s.scan_files(_hostile_files(bank))
+        assert GUARD.breaker._failures == failures
+        assert [METRICS.get("trivy_tpu_secret_prefilter_path_total",
+                            path=p) for p in paths] == before
 
 
 # ---------------------------------------------------------------------------

@@ -519,7 +519,7 @@ class CargoLockAnalyzer(Analyzer):
         return path.endswith("Cargo.lock")
 
     def analyze(self, path, content):
-        from ...compat import tomllib
+        import tomllib
         try:
             doc = tomllib.loads(content.decode(errors="replace"))
         except tomllib.TOMLDecodeError:
@@ -545,7 +545,7 @@ class PoetryLockAnalyzer(PostAnalyzer):
         return path.endswith(("poetry.lock", "pyproject.toml"))
 
     def post_analyze(self, files: dict) -> Optional[AnalysisResult]:
-        from ...compat import tomllib
+        import tomllib
         apps = []
         for path in sorted(files):
             if not path.endswith("poetry.lock"):

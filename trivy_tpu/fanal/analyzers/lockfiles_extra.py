@@ -14,7 +14,7 @@ from __future__ import annotations
 import json
 import re
 import struct
-from ...compat import tomllib
+import tomllib
 import zlib
 import xml.etree.ElementTree as ET
 from typing import Optional

@@ -546,9 +546,8 @@ METRICS.declare(
     "trivy_tpu_secret_prefilter_path_total", "counter",
     "Keyword-prefilter launches by the path that actually served them "
     "(path=\"pallas\"/\"jnp\"/\"host\"): pallas = the TPU shift-or "
-    "kernel, jnp = ac.shiftor_scan (CPU, mesh, or a logged pallas "
-    "downgrade), host = small batches, open-breaker fallback, and "
-    "device errors.")
+    "kernel, jnp = ac.shiftor_scan (CPU and mesh), host = small "
+    "batches, open-breaker fallback, and device errors.")
 METRICS.declare(
     "trivy_tpu_secret_scan_bytes_total", "counter",
     "Bytes through the keyword prefilter, by serving path "

@@ -166,7 +166,7 @@ def _parse_plain_docs(path: str, text: str):
             data = json.loads(text)
             return data if isinstance(data, list) else [data]
         if base.endswith(".toml"):
-            from ..compat import tomllib
+            import tomllib
             return [tomllib.loads(text)]
     except Exception:
         return []

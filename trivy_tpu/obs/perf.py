@@ -320,7 +320,7 @@ class DispatchLedger:
 
     def aggregate(self) -> dict:
         """→ the ledger's process totals — the bench-tail /
-        device-child `graftprof` block perfcheck consumes."""
+        `graftprof` block perfcheck consumes."""
         with self._lock:
             shapes = [dict(v) for v in self._shapes.values()]
             transfers = dict(self._transfers)

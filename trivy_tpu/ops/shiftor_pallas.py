@@ -1,38 +1,28 @@
 """Pallas TPU kernel for the exact shift-or secret keyword engine.
 
-The jnp fallback (`ops.ac.shiftor_scan`) re-reads the packed word
-plane from HBM once per (keyword, state word) pair — a `lax.scan` over
-~93 keywords × state_words ≈ 650 full HBM passes over a [B, 16384]
-uint32 plane. This kernel is the TPU-first form of the same exact
-match: each chunk row's word planes are DMA'd into VMEM exactly once
-and every keyword's FULL multi-word state advances there, so HBM
-traffic is `state_words` reads of the input plus a tiny hit-row write,
-and the VPU does the K×L×W compares out of VMEM. Where the v1 kernel
-(ops/prefilter_pallas, removed) tested only each keyword's packed
-4-byte prefix and left a host substring confirm behind, this one
-verifies every word of every keyword — the output bitmask is exact and
-the host stage runs regexes only.
+The jnp path (`ops.ac.shiftor_scan`) re-reads the packed word plane
+from HBM once per (keyword, state word) pair — a `lax.scan` over ~93
+keywords × state_words ≈ 650 full HBM passes over a [B, 16384] uint32
+plane. This kernel is the TPU-first form of the same exact match: each
+[128, 128] tile of chunk positions is DMA'd into VMEM once per state
+word and every keyword's FULL multi-word state is checked there, so
+HBM traffic is `state_words` reads of the input plus a tiny hit-row
+write. Every word of every keyword is verified — the output bitmask
+is exact and the host stage runs regexes only.
 
-Layout (v1's trick, extended to multi-word states): pattern states
-live on the 128-lane axis — one lane per keyword, the bank padded to
-exactly 128 — and each keyword's state is `state_words` packed 4-byte
-words (ops/ac.py module docstring has the shift-or derivation).
-Positions must then be lane-BROADCAST, which is only cheap when the
-position values sit in sublanes — so XLA pre-transposes each chunk
-row's [128, 128] word tile per state word (batched bandwidth-bound
-shuffles inside the same jit; plane w is the base word plane shifted
-4w bytes, so a match's later words read past the column into the
-neighbouring tile without any lane-unaligned slicing in the kernel).
-The kernel walks the 128 columns; each step extracts one [128, 1]
-position column PER STATE WORD, broadcasts it across the keyword
-lanes, ANDs the masked-XOR equalities over the words (int32, not
-bool: Mosaic cannot relayout i1 loop carries), and OR-accumulates the
-per-position verdict into an int32 [128, 128] accumulator. A final
-sublane reduction yields the per-row keyword hit vector.
+Layout: positions fill the whole tile (sublanes × lanes, no
+transpose), keywords are a `fori_loop` whose word/mask operands are
+SMEM scalars broadcast by the VPU. Plane w is the base word plane
+shifted 4w bytes (precomputed by XLA inside the same jit), so a
+match's later words read past the tile without lane-unaligned slicing
+in the kernel. Per keyword the kernel ANDs the masked-XOR equalities
+over the state words, reduces "any position" to one value and writes
+it into that keyword's output lane.
 
-The static unroll is 128 columns × state_words (7 for the builtin
-bank, ~6k primitives): compile time scales with the longest keyword,
-paid once per chunk-batch shape.
+The keyword loop keeps one tile-sized temporary live at a time. The
+previous form unrolled 128 position columns × state_words statically
+and needed ~54 MiB of scoped VMEM for the builtin bank, which v5e's
+16 MiB limit refuses; this one compiles in well under a second.
 
 Output: int32[B, W] packed keyword bitmask, identical layout to
 `ac.shiftor_scan` — the host decode stage is shared.
@@ -52,29 +42,21 @@ from jax.experimental.pallas import tpu as pltpu
 K_LANES = 128  # keyword bank padded to one full lane register
 
 
-def _kernel(y_ref, kww_ref, kwm_ref, out_ref):
+def _kernel(kww_ref, kwm_ref, y_ref, out_ref):
     n_state = y_ref.shape[1]
-    # hoist the per-word refs: one VMEM read each, reused by all 128
-    # column steps
-    ys = [y_ref[0, w] for w in range(n_state)]         # [128, 128] each
-    kww = [jax.lax.slice(kww_ref[:], (w, 0), (w + 1, K_LANES))
-           for w in range(n_state)]                    # [1, 128] each
-    kwm = [jax.lax.slice(kwm_ref[:], (w, 0), (w + 1, K_LANES))
-           for w in range(n_state)]
-    acc = jnp.zeros((K_LANES, K_LANES), dtype=jnp.int32)
-    # static unroll: dynamic lane indices must be 128-aligned in
-    # Mosaic, but static single-lane slices lower to plain relayouts
-    for j in range(K_LANES):
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, K_LANES), 1)
+
+    def keyword(k, hits):
         m = None
         for w in range(n_state):
-            col = jax.lax.slice(ys[w], (0, j), (K_LANES, j + 1))
-            v = jnp.broadcast_to(col, (K_LANES, K_LANES))  # pos × kw
-            eq = (((v ^ kww[w]) & kwm[w]) == 0).astype(jnp.int32)
+            eq = ((y_ref[0, w] ^ kww_ref[w, k]) & kwm_ref[w, k]) == 0
             m = eq if m is None else (m & eq)
-        acc = acc | m
-    # rows of acc are position-residues; OR over them (max of 0/1
-    # entries) gives "keyword k occurs anywhere in this chunk row"
-    out_ref[0] = jnp.max(acc, axis=0, keepdims=True)     # [1, 128]
+        hit = jnp.max(m.astype(jnp.int32), axis=0, keepdims=True)
+        hit = jnp.max(hit, axis=1, keepdims=True)          # [1, 1]
+        return jnp.where(lane == k, hit, hits)
+
+    out_ref[0] = jax.lax.fori_loop(0, kww_ref.shape[1], keyword,
+                                   jnp.zeros((1, K_LANES), jnp.int32))
 
 
 @functools.partial(jax.jit,
@@ -86,6 +68,9 @@ def shiftor(kw_words, kw_masks, kw_bits, chunks, *, n_words: int,
     occurs in the chunk). kw_* come from `pack_bank`."""
     b, length = chunks.shape
     n_state = kw_words.shape[0]
+    # keywords past 32 * n_words fall outside the returned words, so
+    # the loop never visits those padding lanes
+    n_kw = min(K_LANES, 32 * n_words)
     c = chunks.astype(jnp.uint32)
     pad = jnp.pad(c, ((0, 0), (0, 4)))
     w4 = (pad[:, :length]
@@ -94,37 +79,30 @@ def shiftor(kw_words, kw_masks, kw_bits, chunks, *, n_words: int,
           | (pad[:, 3:length + 3] << 24)).astype(jnp.int32)
     # state-word planes: plane w is w4 shifted 4w bytes left (row-
     # locally — chunk rows are independent), so the kernel's word-w
-    # compare at column position p reads w4[p + 4w] with every slice
-    # sublane-aligned at 0. Zero tail padding cannot false-positive:
-    # no keyword word has a NUL under its mask.
+    # compare at position p reads w4[p + 4w]. Zero tail padding cannot
+    # false-positive: no keyword word has a NUL under its mask.
     w4p = jnp.pad(w4, ((0, 0), (0, 4 * n_state)))
     planes = jnp.stack([w4p[:, 4 * w:4 * w + length]
                         for w in range(n_state)], axis=1)  # [B, W, L]
-    # positions into sublanes: batched [128, 128] tile transposes
     n_tiles = length // (K_LANES * K_LANES)
-    y = planes.reshape(b, n_state, n_tiles, K_LANES, K_LANES) \
-        .transpose(0, 2, 1, 4, 3) \
-        .reshape(b * n_tiles, n_state, K_LANES, K_LANES)
-    grid_b = y.shape[0]
+    y = planes.reshape(b, n_state, length // K_LANES, K_LANES)
     hits = pl.pallas_call(
         _kernel,
-        grid=(grid_b,),
+        grid=(b, n_tiles),
         in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, n_state, K_LANES, K_LANES),
-                         lambda i: (i, 0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((n_state, K_LANES), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((n_state, K_LANES), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
+                         lambda i, t: (i, 0, t, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, K_LANES), lambda i: (i, 0, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((grid_b, 1, K_LANES),
+        out_specs=pl.BlockSpec((1, 1, K_LANES),
+                               lambda i, t: (i * n_tiles + t, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b * n_tiles, 1, K_LANES),
                                        jnp.int32),
         interpret=interpret,
-    )(y, kw_words, kw_masks)
-    # a chunk row spans L/16384 grid rows; OR them back together.
+        name="shiftor",
+    )(kw_words[:, :n_kw], kw_masks[:, :n_kw], y)
+    # a chunk row spans L/16384 tiles; OR them back together.
     # Pack bits: entries are 0/1, so bit-weighted group sums equal
     # bitwise OR within each 32-keyword word.
     row_hits = jnp.max(hits.reshape(b, n_tiles, K_LANES), axis=1)

@@ -36,19 +36,14 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..db.table import AdvisoryTable
 from ..detect import feed as _feed
 from ..ops import join as J
 from ..ops import next_pow2 as _next_pow2
 from ..resilience.hostjoin import CompactBits
-
-try:  # jax ≥ 0.8 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
 
 def make_mesh(n_devices: int | None = None, db_shards: int = 1,
               devices=None) -> Mesh:
@@ -282,12 +277,14 @@ class MeshDetector:
         # meshguard rebuild path gets table re-sharding for free by
         # constructing a fresh detector over the survivor mesh
         self.st = shard_table(table, db)
-        # upload the sharded table once; every detect() reuses the
-        # device copies (table.device_arrays() analog for the mesh path)
+        # upload the sharded table once, each db shard straight to the
+        # devices of its db column; every detect() reuses the device
+        # copies, so a dispatch moves only the query descriptors
+        on_db = NamedSharding(mesh, P("db"))
         self._st_dev = ShardedTable(
-            lo_tok=jax.device_put(self.st.lo_tok),
-            hi_tok=jax.device_put(self.st.hi_tok),
-            flags=jax.device_put(self.st.flags),
+            lo_tok=jax.device_put(self.st.lo_tok, on_db),
+            hi_tok=jax.device_put(self.st.hi_tok, on_db),
+            flags=jax.device_put(self.st.flags, on_db),
             row_offset=self.st.row_offset, row_len=self.st.row_len)
 
     def _slice_row_bytes(self) -> int:
@@ -314,8 +311,10 @@ class MeshDetector:
         """Ship slice k's (cached) sharded host stacks — the
         graftstream SliceCache upload hook."""
         st = self._host_mesh_slice(k)
-        arrays = (jax.device_put(st.lo_tok), jax.device_put(st.hi_tok),
-                  jax.device_put(st.flags))
+        on_db = NamedSharding(self.mesh, P("db"))
+        arrays = (jax.device_put(st.lo_tok, on_db),
+                  jax.device_put(st.hi_tok, on_db),
+                  jax.device_put(st.flags, on_db))
         nbytes = st.lo_tok.nbytes + st.hi_tok.nbytes + st.flags.nbytes
         return arrays, nbytes
 
@@ -799,11 +798,10 @@ def partition_queries(st: ShardedTable, q_start: np.ndarray,
 def _sharded_csr_join(mesh: Mesh, adv_lo, adv_hi, adv_flags, ver_tok,
                       qs, qc, qv, total, t_pad: int):
     def local(adv_lo, adv_hi, adv_flags, ver_tok, qs, qc, qv, total):
-        if hasattr(jax.lax, "pcast"):
-            # newer jax tracks varying-manual-axes (VMA): the
-            # replicated version pool must be cast to varying before
-            # it meets the per-device descriptors
-            ver_tok = jax.lax.pcast(ver_tok, ("dp", "db"), to="varying")
+        # jax tracks varying-manual-axes (VMA): the replicated version
+        # pool must be cast to varying before it meets the per-device
+        # descriptors
+        ver_tok = jax.lax.pcast(ver_tok, ("dp", "db"), to="varying")
         bits = J._csr_core(adv_lo[0], adv_hi[0], adv_flags[0], ver_tok,
                            qs[0, 0], qc[0, 0], qv[0, 0], total[0, 0],
                            t_pad)
@@ -819,6 +817,14 @@ def _sharded_csr_join(mesh: Mesh, adv_lo, adv_hi, adv_flags, ver_tok,
     return f(adv_lo, adv_hi, adv_flags, ver_tok, qs, qc, qv, total)
 
 
+def _cell_descriptors(mesh: Mesh, part: QueryPartition) -> list:
+    """The partition's [DP, S, ...] query descriptors, each cell's
+    slice placed straight on that cell's device."""
+    cells = NamedSharding(mesh, P("dp", "db"))
+    return [jax.device_put(a, cells) for a in
+            (part.q_start, part.q_count, part.q_ver, part.total)]
+
+
 def sharded_csr_join(mesh: Mesh, st, ver_tok, part: QueryPartition,
                      n_pairs: int) -> np.ndarray:
     """CSR variant of sharded_pair_join: ships [DP, S, Q_loc]
@@ -828,8 +834,7 @@ def sharded_csr_join(mesh: Mesh, st, ver_tok, part: QueryPartition,
     bits = jax.device_get(_sharded_csr_join(
         mesh, jnp.asarray(st.lo_tok), jnp.asarray(st.hi_tok),
         jnp.asarray(st.flags), jnp.asarray(ver_tok),
-        jax.device_put(part.q_start), jax.device_put(part.q_count),
-        jax.device_put(part.q_ver), jax.device_put(part.total),
+        *_cell_descriptors(mesh, part),
         part.t_loc))
     METRICS.inc("trivy_tpu_detect_transfer_bytes_total",
                 float(bits.nbytes), path="dense")
@@ -845,8 +850,7 @@ def _sharded_csr_join_compact(mesh: Mesh, adv_lo, adv_hi, adv_flags,
                               ver_tok, qs, qc, qv, total, t_pad: int,
                               h_cap: int):
     def local(adv_lo, adv_hi, adv_flags, ver_tok, qs, qc, qv, total):
-        if hasattr(jax.lax, "pcast"):
-            ver_tok = jax.lax.pcast(ver_tok, ("dp", "db"), to="varying")
+        ver_tok = jax.lax.pcast(ver_tok, ("dp", "db"), to="varying")
         bits = J._csr_core(adv_lo[0], adv_hi[0], adv_flags[0], ver_tok,
                            qs[0, 0], qc[0, 0], qv[0, 0], total[0, 0],
                            t_pad)
@@ -886,8 +890,7 @@ def sharded_csr_join_compact(mesh: Mesh, st, ver_tok,
     out = _sharded_csr_join_compact(
         mesh, jnp.asarray(st.lo_tok), jnp.asarray(st.hi_tok),
         jnp.asarray(st.flags), jnp.asarray(ver_tok),
-        jax.device_put(part.q_start), jax.device_put(part.q_count),
-        jax.device_put(part.q_ver), jax.device_put(part.total),
+        *_cell_descriptors(mesh, part),
         part.t_loc, h_cap)
     hit_idx, hit_bits, n_hits = jax.device_get(out[:3])
     METRICS.inc("trivy_tpu_detect_transfer_bytes_total",
