@@ -436,10 +436,9 @@ def bench_archive_e2e(table):
         hits_serial = sum(scan_one(p, serial_opts)
                           for p in paths[1:])
         dt_serial = time.perf_counter() - t1
-        # graftwatch attribution pass (UNTIMED — recording arms the
-        # detect engine's fence): a subset re-scan under the collector
-        # yields the walker/analyzer/applier split plus walker-pool
-        # occupancy (layer-walk busy time / walkers × wall)
+        # graftwatch attribution pass (untimed): a subset re-scan
+        # under the collector yields the walker/analyzer/applier split
+        # (span self times) plus walker-pool occupancy
         from trivy_tpu.obs import COLLECTOR
         attr_paths = paths[:16]
         COLLECTOR.enable()
@@ -453,7 +452,7 @@ def bench_archive_e2e(table):
         attr_wall_ms = (time.perf_counter() - ta) * 1e3
 
     def ms(name):
-        return phase.get(name, {}).get("total_ms", 0.0)
+        return phase.get(name, {}).get("self_ms", 0.0)
 
     breakdown = {
         # pipeline mode: layer-walk spans run on walker threads and
@@ -1593,10 +1592,8 @@ def run_device_half(table, detector, images) -> tuple[dict, list]:
 
     host_s, device_s, asm_s, asm_compact_s, n_pairs, transfer = \
         split_timings(detector, images)
-    # per-phase graftscope breakdown from an untimed subset pass:
-    # recording arms the detect engine's device fence, which serializes
-    # the dispatch/transfer overlap — never record during the TIMED
-    # pass above, only here where sub_hits (a parity check) is the goal
+    # per-phase graftscope breakdown (span self times) from an untimed
+    # subset pass, where sub_hits (a parity check) is the goal
     from trivy_tpu.obs import COLLECTOR
     COLLECTOR.enable()
     sub_hits = run_device(detector, images[:BASELINE_IMAGES])

@@ -573,6 +573,20 @@ def watch_server(tmp_path_factory):
     state.close()
 
 
+def _rpc_trace(base: str, tid: str, wait_s: float = 2.0) -> dict:
+    """→ /debug/traces for `tid` once its `server.rpc` root is there:
+    the handler records the root span after the response is on the
+    wire, so a client that asks at once can beat it."""
+    deadline = time.monotonic() + wait_s
+    while True:
+        doc = json.loads(urllib.request.urlopen(
+            base + f"/debug/traces?trace_id={tid}").read())
+        if "server.rpc" in {s["name"] for s in doc["spans"]} \
+                or time.monotonic() > deadline:
+            return doc
+        time.sleep(0.01)
+
+
 class TestDebugEndpoints:
     def test_debug_traces_serves_the_rpc_trace(self, watch_server):
         req = urllib.request.Request(
@@ -583,8 +597,7 @@ class TestDebugEndpoints:
             method="POST")
         with urllib.request.urlopen(req) as r:
             tid = r.headers.get("X-Trivy-Trace-Id")
-        doc = json.loads(urllib.request.urlopen(
-            watch_server + f"/debug/traces?trace_id={tid}").read())
+        doc = _rpc_trace(watch_server, tid)
         assert doc["trace_id"] == tid
         assert "server.rpc" in {s["name"] for s in doc["spans"]}
         # no trace_id → the buffer listing
@@ -602,9 +615,7 @@ class TestDebugEndpoints:
                      "X-Trivy-Parent-Span": "c0ffee0012345678"},
             method="POST")
         urllib.request.urlopen(req).read()
-        doc = json.loads(urllib.request.urlopen(
-            watch_server + "/debug/traces?trace_id=" + "ab" * 16)
-            .read())
+        doc = _rpc_trace(watch_server, "ab" * 16)
         root = next(s for s in doc["spans"]
                     if s["name"] == "server.rpc")
         assert root["parent_id"] == "c0ffee0012345678"

@@ -202,6 +202,159 @@ class TestTracer:
         json.dumps(doc)  # must be JSON-serializable
 
 
+def _burn(seconds: float) -> None:
+    """Spin the calling thread's CPU for `seconds` of its own time."""
+    import time
+    t0 = time.thread_time()
+    while time.thread_time() - t0 < seconds:
+        pass
+
+
+class TestSpanTime:
+    def test_cpu_is_the_threads_own(self):
+        import threading
+        import time
+        stop = threading.Event()
+        other = threading.Thread(
+            target=lambda: [_burn(0.005) for _ in iter(stop.is_set, True)])
+        other.start()
+        try:
+            with span("t.sleeper") as sp:
+                time.sleep(0.1)
+        finally:
+            stop.set()
+            other.join()
+        # another thread burned CPU all along; the sleeping span's own
+        # thread used next to none
+        assert sp.dur >= 0.1
+        assert sp.cpu < 0.03
+
+    def test_self_time_subtracts_same_thread_children_only(self):
+        import contextvars
+        import threading
+        with span("t.parent") as parent:
+            _burn(0.02)
+            with span("t.child") as child:
+                _burn(0.03)
+            # a child on another thread, under this context, runs
+            # beside the parent: it subtracts nothing
+            ctx = contextvars.copy_context()
+            got = []
+            t = threading.Thread(target=ctx.run, args=(
+                lambda: got.append(_in_span("t.remote", 0.03)),))
+            t.start()
+            t.join()
+        remote = got[0]
+        assert remote.parent_id == parent.span_id
+        assert parent.child_dur == pytest.approx(child.dur)
+        assert parent.child_cpu == pytest.approx(child.cpu)
+        assert parent.self_dur == pytest.approx(parent.dur - child.dur)
+        assert parent.self_cpu == pytest.approx(parent.cpu - child.cpu)
+        assert parent.self_cpu >= 0.02 and child.self_cpu >= 0.03
+        assert remote.self_cpu >= 0.03
+        # the remote child's time stays in the parent's self wall time
+        assert parent.self_dur >= remote.dur
+
+    def test_span_counters_aggregate_self_time(self):
+        name = "t.counted"
+        key = (("span", name),)
+
+        def read():
+            return (METRICS.family("trivy_tpu_spans_total").get(key, 0.0),
+                    METRICS.family(
+                        "trivy_tpu_span_self_seconds_total").get(key, 0.0),
+                    METRICS.family(
+                        "trivy_tpu_span_self_cpu_seconds_total").get(
+                            key, 0.0))
+        n0, wall0, cpu0 = read()
+        spans = []
+        for _ in range(3):
+            with span(name) as sp:
+                _burn(0.002)
+                with span("t.counted_child"):
+                    _burn(0.002)
+            spans.append(sp)
+        n1, wall1, cpu1 = read()
+        assert n1 - n0 == 3
+        assert wall1 - wall0 == pytest.approx(
+            sum(s.self_dur for s in spans))
+        assert cpu1 - cpu0 == pytest.approx(sum(s.self_cpu for s in spans))
+        assert obs_trace.span_totals()[name][0] == n1
+        # /metrics exports the aggregate under the span label
+        fams = parse_exposition(METRICS.render())
+        samples = {(n, l.get("span")): v for n, l, v in
+                   fams["trivy_tpu_spans_total"]["samples"]}
+        assert samples[("trivy_tpu_spans_total", name)] == n1
+        assert "trivy_tpu_span_self_cpu_seconds_total" in fams
+
+    def test_phase_totals_count_since_recording_began(self):
+        with span("t.phase"):
+            pass
+        COLLECTOR.enable()
+        try:
+            for _ in range(2):
+                with span("t.phase"):
+                    _burn(0.001)
+            phases = COLLECTOR.phase_totals()
+        finally:
+            COLLECTOR.disable()
+        assert phases["t.phase"]["count"] == 2
+        assert phases["t.phase"]["self_cpu_ms"] >= 2.0
+        assert phases["t.phase"]["self_ms"] >= \
+            phases["t.phase"]["self_cpu_ms"] * 0.5
+
+    def test_span_ids_are_16_hex_and_unique_across_processes(self):
+        import re
+        import subprocess
+        import sys
+        ids = []
+        for _ in range(2000):
+            with span("t.id") as sp:
+                pass
+            ids.append(sp.span_id)
+        assert len(set(ids)) == len(ids)
+        assert all(re.fullmatch(r"[0-9a-f]{16}", i) for i in ids)
+        code = ("from trivy_tpu.obs.trace import span\n"
+                "with span('x') as sp: pass\nprint(sp.span_id)")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        others = {subprocess.run(
+            [sys.executable, "-c", code], cwd=root, check=True,
+            capture_output=True, text=True).stdout.strip()
+            for _ in range(3)}
+        # each process draws its own prefix
+        assert len({i[:8] for i in others} | {ids[0][:8]}) == 4
+
+    def test_span_lands_inside_an_annotation_in_the_xplane(self, tmp_path):
+        import jax
+        from jax.profiler import ProfileData
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with jax.profiler.TraceAnnotation("t.enclosing"):
+                with span("t.profiled"):
+                    _burn(0.005)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = _glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                             recursive=True)
+        events = {}
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                for line in plane.lines:
+                    for ev in line.events:
+                        if ev.name.startswith("t."):
+                            events[ev.name] = (ev.start_ns, ev.start_ns
+                                               + ev.duration_ns)
+        outer, inner = events["t.enclosing"], events["t.profiled"]
+        assert outer[0] <= inner[0] < inner[1] <= outer[1]
+        assert inner[1] - inner[0] >= 5e6
+
+
+def _in_span(name: str, seconds: float):
+    with span(name) as sp:
+        _burn(seconds)
+    return sp
+
+
 # ---------------------------------------------------------------------------
 # golden chrome trace from a tiny scan
 
@@ -238,14 +391,15 @@ class TestTinyScanTrace:
         names = {e["name"] for e in events}
         assert {"scan", "scan.apply_layers", "fanal.apply_layers",
                 "scan.build_queries", "scan.detect", "detect.prepare",
-                "detect.dispatch", "detect.device_fence",
+                "detect.dispatch", "detect.fetch",
                 "detect.device_wait", "detect.assemble",
                 "scan.assemble_results"} <= names
         # detect phases nest inside scan.detect by both parentage and
-        # time containment
+        # time containment — the fetch and assembly on their pools'
+        # threads too
         by_id = {e["args"]["span_id"]: e for e in events}
         detect = next(e for e in events if e["name"] == "scan.detect")
-        for phase in ("detect.prepare", "detect.dispatch",
+        for phase in ("detect.prepare", "detect.dispatch", "detect.fetch",
                       "detect.device_wait", "detect.assemble"):
             ev = next(e for e in events if e["name"] == phase)
             assert by_id[ev["args"]["parent_id"]] is detect
@@ -256,6 +410,39 @@ class TestTinyScanTrace:
         prep = next(e for e in events if e["name"] == "detect.prepare")
         assert prep["args"]["n_pairs"] >= 1
         assert prep["args"]["t_pad"] >= prep["args"]["n_pairs"]
+
+    def test_recording_keeps_the_detectd_path(self, tmp_path):
+        """A recorded scan through a scanner with detectd runs what
+        the server runs: the handler waits on detectd, whose merged
+        round dispatches."""
+        from trivy_tpu import types as T
+        from trivy_tpu.detect import SchedOptions
+        from trivy_tpu.fanal.artifact import ImageArchiveArtifact
+        from trivy_tpu.fanal.cache import MemoryCache
+        from trivy_tpu.scanner import LocalScanner
+        img = str(tmp_path / "img.tar")
+        make_image(img, [{
+            "etc/os-release": ALPINE_OS_RELEASE,
+            "lib/apk/db/installed": APK_INSTALLED,
+        }])
+        cache = MemoryCache()
+        ref = ImageArchiveArtifact(img, cache,
+                                   scanners=("vuln",)).inspect()
+        scanner = LocalScanner(cache, _fixture_table(),
+                               sched=SchedOptions())
+        COLLECTOR.enable()
+        try:
+            results, _ = scanner.scan(
+                ref.name, ref.id, ref.blob_ids,
+                T.ScanOptions(scanners=("vuln",)))
+        finally:
+            COLLECTOR.disable()
+            scanner.close()
+        assert any(r.vulnerabilities for r in results)
+        names = {s.name for s in COLLECTOR.drain()}
+        assert {"detectd.wait", "detectd.round", "detect.fetch",
+                "detect.assemble"} <= names
+        assert "detect.device_wait" not in names
 
     def test_trace_topology_matches_golden(self, tmp_path):
         """The span topology (parent→child name edges) of a tiny vuln

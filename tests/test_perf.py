@@ -759,7 +759,7 @@ class TestAcceptanceDrill:
 
         # ---- perfcheck on two recorded tails ------------------------
         ips = 24 / max(sum(
-            p.get("total_ms", 0.0)
+            p.get("self_ms", 0.0)
             for n, p in phases.items() if n == "server.rpc") / 1e3,
             1e-6)
         tail = {"scan_throughput": round(ips, 2),
@@ -804,3 +804,57 @@ class TestCompileCache:
         want = os.path.join(root, ".jax_cache")
         assert compile_cache.enable() == want
         assert cache_config.jax_compilation_cache_dir == want
+
+    def test_programs_count_compiled_then_loaded(self, cache_config,
+                                                 monkeypatch, tmp_path):
+        """A jit that misses a fresh cache compiles; the same program
+        after the in-memory caches are cleared loads from it. The
+        counters and watch() agree, and so does the ledger's split."""
+        import jax
+        import numpy as np
+        from jax._src import compilation_cache as jcc
+
+        from trivy_tpu import compile_cache
+        from trivy_tpu.obs.perf import DispatchLedger
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        compile_cache.enable()
+        assert compile_cache.listening()
+        min_s = cache_config.jax_persistent_cache_min_compile_time_secs
+        cache_config.update("jax_compilation_cache_dir", str(tmp_path))
+        cache_config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jcc.reset_cache()
+
+        def count(outcome):
+            return (METRICS.get("trivy_tpu_xla_programs_total",
+                                outcome=outcome),
+                    METRICS.get("trivy_tpu_xla_program_seconds_total",
+                                outcome=outcome))
+        f = jax.jit(lambda x: x * 7 + 3)
+        x = np.arange(11, dtype=np.int32)
+        try:
+            c0, l0 = count("compiled"), count("loaded")
+            with compile_cache.watch() as first:
+                np.asarray(f(x))
+            c1, l1 = count("compiled"), count("loaded")
+            jax.clear_caches()
+            with compile_cache.watch() as second:
+                np.asarray(f(x))
+            c2, l2 = count("compiled"), count("loaded")
+        finally:
+            cache_config.update(
+                "jax_persistent_cache_min_compile_time_secs", min_s)
+            jcc.reset_cache()
+        assert first.compiled >= 1 and first.loaded == 0
+        assert second.compiled == 0 and second.loaded >= 1
+        assert c1[0] - c0[0] == first.compiled and l1 == l0
+        assert l2[0] - l1[0] == second.loaded and c2 == c1
+        assert c1[1] > c0[1] and l2[1] > l1[1]
+        led = DispatchLedger()
+        led.note_compile("detect", 256, 0, 40.0,
+                         loaded=first.compiled == 0)
+        led.note_compile("detect", 256, 0, 3.0,
+                         loaded=second.compiled == 0)
+        row = led.shape_table()[0]
+        assert (row["compiles"], row["compile_ms"]) == (1, 40.0)
+        assert (row["loads"], row["load_ms"]) == (1, 3.0)
+        assert led.aggregate()["loads"] == 1

@@ -9,6 +9,7 @@ import glob
 import os
 import random
 import threading
+import time
 
 import pytest
 
@@ -337,6 +338,33 @@ class TestMetricsPerDispatch:
         sched.close()
         det.close()
         assert METRICS.get("trivy_tpu_dispatch_depth") == 0
+
+    def test_queue_wait_is_observed_once_per_request(self, table):
+        det = BatchDetector(table)
+        sched = DispatchScheduler(det, SchedOptions(coalesce_wait_ms=2.0))
+        name = "trivy_tpu_detect_queue_wait_seconds"
+        _r, s0, c0 = METRICS.hist_get(name)
+        requests = _rand_requests(11, 12)
+        t0 = time.perf_counter()
+        threads = [threading.Thread(target=sched.detect_many, args=(r,))
+                   for r in requests]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        elapsed = time.perf_counter() - t0
+        _r, s1, c1 = METRICS.hist_get(name)
+        # one observation per request that reached a dispatch (a
+        # request whose batches prep to no pairs resolves unqueued),
+        # however many chunks its slots split over
+        queued = sum(1 for r in requests
+                     if any(p is not None and p.n_pairs
+                            for p in map(det._prepare, filter(None, r))))
+        sched.close()
+        det.close()
+        assert c1 - c0 == queued > 0
+        assert 0 <= s1 - s0 <= queued * elapsed
+
 
 class _FakeReq:
     """Bare stand-in for _Request — the fair-queue helpers only read

@@ -28,11 +28,12 @@ from typing import Any, NamedTuple, Optional
 
 import numpy as np
 
+from .. import compile_cache
 from .. import version as V
 from ..db.table import AdvisoryTable
 from ..log import get as _get_logger
 from ..metrics import METRICS
-from ..obs import SLO, note_dispatch, recording, span
+from ..obs import SLO, note_dispatch, span
 from ..obs import cost as _cost
 from ..obs.perf import LEDGER, stamp_table_resident
 from ..ops import bucket_ladder, bucket_size
@@ -696,12 +697,14 @@ class BatchDetector:
                     # mid-measurement compile shows up in Perfetto,
                     # and ledger it under the warmup/traffic phase
                     with span("detect.compile", t_pad=t_pad,
-                              h_cap=h_cap, warm=warm):
+                              h_cap=h_cap, warm=warm), \
+                            compile_cache.watch() as got:
                         t0 = time.perf_counter()
                         out = _kernel()
                         compile_ms = (time.perf_counter() - t0) * 1e3
                     LEDGER.note_compile(site, t_pad, h_cap,
-                                        compile_ms, warm=warm)
+                                        compile_ms, warm=warm,
+                                        loaded=got.compiled == 0)
                 else:
                     out = _kernel()
                 self._account_traffic(total, t_pad, warm=warm)
@@ -789,16 +792,18 @@ class BatchDetector:
     def _fetch_or_fallback(self, prep: _Prepared, dev) -> np.ndarray:
         """Fetch one prep's bits; on a supervised failure recompute
         them on the host from the prep's own pair expansion — the
-        request completes with identical bits either way."""
-        try:
-            return self._fetch_bits(dev)
-        except DeviceError:
-            _log.warning("device fetch failed; host-fallback join",
-                         exc_info=True)
-            # one bad device_serving event per dispatch RESOLUTION
-            # (the launch already recorded its optimistic good)
-            SLO.observe_join(False)
-            return self._host_bits(prep)
+        request completes with identical bits either way. Runs on the
+        get thread, under a detect.fetch span."""
+        with span("detect.fetch", n_pairs=prep.n_pairs):
+            try:
+                return self._fetch_bits(dev)
+            except DeviceError:
+                _log.warning("device fetch failed; host-fallback join",
+                             exc_info=True)
+                # one bad device_serving event per dispatch RESOLUTION
+                # (the launch already recorded its optimistic good)
+                SLO.observe_join(False)
+                return self._host_bits(prep)
 
     def _host_bits_merged(self, preps: list, offsets: list,
                           t_pad: int) -> np.ndarray:
@@ -821,31 +826,33 @@ class BatchDetector:
         unique-space result and scatters it back through the plan's
         index map; its fetch-failure rebuild runs the host join over
         the SAME unique descriptor set (then scatters identically) —
-        the hostjoin contract survives dedup by construction."""
-        if isinstance(dev, _feed.PendingExpand):
+        the hostjoin contract survives dedup by construction. Runs on
+        the get thread, under a detect.fetch span."""
+        with span("detect.fetch", merged=len(preps)):
+            if isinstance(dev, _feed.PendingExpand):
+                try:
+                    bits_u = self._fetch_bits(dev.dev)
+                except DeviceError:
+                    # _host_join_csr counts the one bad device_serving
+                    # event itself (unlike the per-prep rebuild below)
+                    _log.warning(
+                        "merged device fetch failed; rebuilding the "
+                        "unique-query join on the host", exc_info=True)
+                    ls, lc, lv, l_total, l_tpad = dev.launch
+                    bits_u = self._host_join_csr(ls, lc, lv, l_total,
+                                                 l_tpad, h_cap=0)
+                return _feed.expand_bits(dev.plan, bits_u, t_pad)
             try:
-                bits_u = self._fetch_bits(dev.dev)
+                return self._fetch_bits(dev)
             except DeviceError:
-                # _host_join_csr counts the one bad device_serving
-                # event itself (unlike the per-prep rebuild below)
-                _log.warning(
-                    "merged device fetch failed; rebuilding the "
-                    "unique-query join on the host", exc_info=True)
-                ls, lc, lv, l_total, l_tpad = dev.launch
-                bits_u = self._host_join_csr(ls, lc, lv, l_total,
-                                             l_tpad, h_cap=0)
-            return _feed.expand_bits(dev.plan, bits_u, t_pad)
-        try:
-            return self._fetch_bits(dev)
-        except DeviceError:
-            _log.warning("merged device fetch failed; rebuilding %d "
-                         "request slices on the host", len(preps),
-                         exc_info=True)
-            # ONE bad device_serving event for the whole merged
-            # dispatch — the per-prep host rebuild below must not
-            # multiply a single fetch failure by the coalesce factor
-            SLO.observe_join(False)
-            return self._host_bits_merged(preps, offsets, t_pad)
+                _log.warning("merged device fetch failed; rebuilding %d "
+                             "request slices on the host", len(preps),
+                             exc_info=True)
+                # ONE bad device_serving event for the whole merged
+                # dispatch — the per-prep host rebuild below must not
+                # multiply a single fetch failure by the coalesce factor
+                SLO.observe_join(False)
+                return self._host_bits_merged(preps, offsets, t_pad)
 
     def _dispatch_impl(self, prep: _Prepared):
         """Launch the pair join; returns the device array (async).
@@ -1023,41 +1030,30 @@ class BatchDetector:
         fetch streams on the shared get thread, and assembly runs on
         the small worker pool overlapped with later batches' transfers.
         In-flight dispatches are bounded by max_pairs_in_flight.
-
-        Under graftscope recording the legacy staged-but-serialized
-        path runs instead: it fences the device between phases so
-        compile/execute/transfer are attributable to their spans —
-        tracing trades the overlap for attribution (bench.py records
-        phase breakdowns on an untimed pass for the same reason)."""
+        Recording a trace runs this same pipeline: each stage's span
+        times it where it runs (the fetch on the get thread, the
+        handler's wait for it as detect.device_wait)."""
         if len(self.table) == 0:
             return [[] for _ in batches]
-        if recording():
-            return self._detect_many_traced(batches)
-        return self._detect_many_pipelined(batches)
-
-    def _detect_many_pipelined(self,
-                               batches: list[list[PkgQuery]]
-                               ) -> list[list[Hit]]:
         out: list = [[] for _ in batches]
         window: deque = deque()   # (idx, prep, get_future) in order
         asm_futs: list = []       # (idx, assemble future)
-        state = {"pairs": 0, "wait_s": 0.0}
+        state = {"pairs": 0}
         n_queries = n_pairs_total = 0
 
         def drain_one():
             idx, prep, gf = window.popleft()
             t_get = time.perf_counter()
             try:
-                bits = gf.result()
+                with span("detect.device_wait", n_pairs=prep.n_pairs):
+                    bits = gf.result()
             finally:
                 # decrement even when the fetch raises — the entry is
                 # already popped, so the outer cleanup can't see it
                 METRICS.gauge_add("trivy_tpu_dispatch_depth", -1.0)
                 state["pairs"] -= int(prep.pair_row.shape[0])
-            now = time.perf_counter()
             METRICS.observe("trivy_tpu_device_get_stall_seconds",
-                            now - t_get)
-            state["wait_s"] += now - t_get
+                            time.perf_counter() - t_get)
             # copy_context: the assemble worker inherits this thread's
             # trace id / span parentage (graftscope is contextvar-based)
             ctx = contextvars.copy_context()
@@ -1108,68 +1104,10 @@ class BatchDetector:
             # gauge ratcheted up forever
             for _ in range(len(window)):
                 METRICS.gauge_add("trivy_tpu_dispatch_depth", -1.0)
-        t_join = time.perf_counter()
         for idx, f in asm_futs:
             out[idx] = f.result()
         METRICS.inc("trivy_tpu_detect_queries_total", n_queries)
         METRICS.inc("trivy_tpu_detect_pairs_total", n_pairs_total)
-        METRICS.inc("trivy_tpu_detect_wait_assemble_seconds_total",
-                    state["wait_s"] + time.perf_counter() - t_join)
-        METRICS.inc("trivy_tpu_detect_hits_total",
-                    sum(len(h) for h in out))
-        return out
-
-    def _detect_many_traced(self,
-                            batches: list[list[PkgQuery]]
-                            ) -> list[list[Hit]]:
-        """Legacy staged path, kept for graftscope recording: all preps,
-        then all dispatches, a device fence, then serialized
-        fetch+assemble — every phase lands in its own span."""
-        prepped = [self._prepare(qs) if qs else None for qs in batches]
-        futures = [None if p is None or p.n_pairs == 0
-                   else self._dispatch(p) for p in prepped]
-        n_active = sum(1 for f in futures if f is not None)
-        METRICS.inc("trivy_tpu_detect_queries_total",
-                    sum(len(qs) for qs in batches))
-        METRICS.inc("trivy_tpu_detect_pairs_total",
-                    sum(p.n_pairs for p in prepped if p is not None))
-        import jax
-        if n_active:
-            # tracing fence: block until every dispatched join has
-            # executed, so XLA compile+execute lands in THIS span and
-            # the device-wait spans below read as pure result transfer
-            with span("detect.device_fence", batches=n_active):
-                jax.block_until_ready(
-                    [f for f in futures if f is not None])
-        t0 = time.perf_counter()
-        METRICS.gauge_add("trivy_tpu_dispatch_depth", float(n_active))
-        in_flight = n_active
-        get_futs = [None if fut is None
-                    else self._get_pool.submit(
-                        contextvars.copy_context().run,
-                        self._fetch_or_fallback, prep, fut)
-                    for prep, fut in zip(prepped, futures)]
-        out = []
-        try:
-            for prep, gf in zip(prepped, get_futs):
-                if gf is None:
-                    out.append([])
-                    continue
-                with span("detect.device_wait", n_pairs=prep.n_pairs):
-                    t_get = time.perf_counter()
-                    bits = gf.result()
-                    METRICS.observe(
-                        "trivy_tpu_device_get_stall_seconds",
-                        time.perf_counter() - t_get)
-                METRICS.gauge_add("trivy_tpu_dispatch_depth", -1.0)
-                in_flight -= 1
-                out.append(self._assemble(prep, bits))
-        finally:
-            if in_flight:
-                METRICS.gauge_add("trivy_tpu_dispatch_depth",
-                                  float(-in_flight))
-        METRICS.inc("trivy_tpu_detect_wait_assemble_seconds_total",
-                    time.perf_counter() - t0)
         METRICS.inc("trivy_tpu_detect_hits_total",
                     sum(len(h) for h in out))
         return out

@@ -228,7 +228,10 @@ class DispatchScheduler:
 
     def detect_many(self, batches: list[list[PkgQuery]]
                     ) -> list[list[Hit]]:
-        parts = self.submit(batches).result()
+        fut = self.submit(batches)
+        # the handler's wait: queue, merged dispatch, device, fetch
+        with span("detectd.wait"):
+            parts = fut.result()
         # assemble HERE, on the requesting thread: the most
         # host-expensive stage keeps the same per-request parallelism
         # as the uncoalesced path (concurrent RPC handlers assemble
@@ -402,37 +405,8 @@ class DispatchScheduler:
                     # so loop once more to flush any residue
                     stopping = True
                     continue
-            # sweep everything already queued (free coalescing), then
-            # hold the window open — but ONLY while a dispatch is in
-            # flight: with an idle device, waiting would trade latency
-            # for nothing, while a busy device makes the wait free
-            # (the request would be queued behind it anyway)
-            deadline = time.monotonic() + opts.coalesce_wait_ms / 1e3
-            while not stopping:
-                stopping |= self._drain_tokens()
-                with self._lock:
-                    pairs = self._fair_pairs
-                if stopping or pairs >= opts.max_pairs_in_flight:
-                    break
-                with self._cv:
-                    busy = self._inflight_pairs > 0
-                timeout = deadline - time.monotonic()
-                if not busy or timeout <= 0:
-                    break
-                try:
-                    tok = self._queue.get(
-                        timeout=min(timeout,
-                                    opts.coalesce_wait_ms / 4e3))
-                except queue_mod.Empty:
-                    continue
-                if tok is None:
-                    stopping = True
-            # graftfair: pop the round in deficit-round-robin order —
-            # a flooding tenant's surplus stays queued (and visible to
-            # the prefetch peek) instead of monopolizing the window
-            with self._lock:
-                pending = self._fair_take_locked(
-                    opts.max_pairs_in_flight)
+            with span("detectd.gather"):
+                stopping, pending = self._gather(stopping)
             if not pending:
                 continue
             METRICS.observe("trivy_tpu_detect_queue_depth",
@@ -446,6 +420,39 @@ class DispatchScheduler:
                     req.fail(e)
             if opts.prefetch:
                 self._prefetch_pending()
+
+    def _gather(self, stopping: bool) -> tuple[bool, list[_Request]]:
+        """Sweep everything already queued (free coalescing), then
+        hold the window open — but ONLY while a dispatch is in flight:
+        with an idle device, waiting would trade latency for nothing,
+        while a busy device makes the wait free (the request would be
+        queued behind it anyway). → (stopping, the round's requests)."""
+        opts = self.opts
+        deadline = time.monotonic() + opts.coalesce_wait_ms / 1e3
+        while not stopping:
+            stopping |= self._drain_tokens()
+            with self._lock:
+                pairs = self._fair_pairs
+            if stopping or pairs >= opts.max_pairs_in_flight:
+                break
+            with self._cv:
+                busy = self._inflight_pairs > 0
+            timeout = deadline - time.monotonic()
+            if not busy or timeout <= 0:
+                break
+            try:
+                tok = self._queue.get(
+                    timeout=min(timeout, opts.coalesce_wait_ms / 4e3))
+            except queue_mod.Empty:
+                continue
+            if tok is None:
+                stopping = True
+        # graftfair: pop the round in deficit-round-robin order — a
+        # flooding tenant's surplus stays queued (and visible to the
+        # prefetch peek) instead of monopolizing the window
+        with self._lock:
+            pending = self._fair_take_locked(opts.max_pairs_in_flight)
+        return stopping, pending
 
     def _observe_dispatch_share(self, pending: list[_Request]) -> None:
         """Per merged round: each participating tenant's fraction of
@@ -516,13 +523,14 @@ class DispatchScheduler:
             dedup_on = self.opts.dedup and getattr(det, "dedup", False)
             stage = getattr(det, "stage_merged", None)
             staged = plan = None
-            if stage is not None:
-                staged = (stage(preps) if dedup_on
-                          else stage(preps, plan=None))
-                plan = staged.plan
-            elif hasattr(det, "dedup"):
-                plan = (_feed.plan_from_preps(preps) if dedup_on
-                        else None)
+            with span("detectd.stage", merged=len(preps)):
+                if stage is not None:
+                    staged = (stage(preps) if dedup_on
+                              else stage(preps, plan=None))
+                    plan = staged.plan
+                elif hasattr(det, "dedup"):
+                    plan = (_feed.plan_from_preps(preps) if dedup_on
+                            else None)
             # backpressure: admit this dispatch only when the in-flight
             # padded pairs leave room (a chunk bigger than the whole
             # budget still goes — alone — once the device drains)
@@ -569,6 +577,8 @@ class DispatchScheduler:
                                       + int(plan.collapsed_by_prep[k]))
                 if not r.queue_charged:
                     r.queue_charged = True
+                    METRICS.observe("trivy_tpu_detect_queue_wait_seconds",
+                                    now - r.t_submit)
                     _cost.charge_queue_ms((now - r.t_submit) * 1e3,
                                           ledger=r.cost)
             seen: set[int] = set()

@@ -239,7 +239,7 @@ class ImageArchiveArtifact(_ImageInspectMixin):
             self.secret_scanner = SecretScanner()
 
     def inspect(self) -> ArtifactReference:
-        with tarfile.open(self.path) as tf:
+        with span("fanal.inspect"), tarfile.open(self.path) as tf:
             names = tf.getnames()
             if "manifest.json" in names:
                 return self._inspect_docker_archive(tf)

@@ -75,6 +75,17 @@ class Registry:
         with self._lock:
             self._values[key] = self._values.get(key, 0.0) + value
 
+    def inc_many(self, values: tuple, **labels):
+        """Several counters of one label set under one lock:
+        `values` is ((name, value), ...) — a finished span's count,
+        self seconds and self CPU seconds (obs/trace.py)."""
+        labels = tuple(sorted(labels.items()))
+        v = self._values
+        with self._lock:
+            for name, value in values:
+                key = (name, labels)
+                v[key] = v.get(key, 0.0) + value
+
     def set_gauge(self, name: str, value: float, **labels):
         key = (name, tuple(sorted(labels.items())))
         with self._lock:
@@ -119,6 +130,13 @@ class Registry:
         with self._lock:
             return sum(v for (n, _labels), v in self._values.items()
                        if n == name)
+
+    def family(self, name: str) -> dict[tuple, float]:
+        """→ {labels: value} of one counter family (labels as the
+        sorted (key, value) tuple)."""
+        with self._lock:
+            return {labels: v for (n, labels), v in self._values.items()
+                    if n == name}
 
     def hist_get(self, name: str, **labels) -> tuple[list, float, int]:
         """→ (bucket_counts, sum, count) for one histogram series."""
@@ -232,9 +250,6 @@ METRICS.declare("trivy_tpu_detect_pairs_total", "counter",
                 "Candidate (package, advisory) pairs joined on device.")
 METRICS.declare("trivy_tpu_detect_hits_total", "counter",
                 "Detected (package, advisory-group) matches.")
-METRICS.declare("trivy_tpu_detect_wait_assemble_seconds_total",
-                "counter",
-                "Wall time in device-result wait plus host assembly.")
 METRICS.declare(
     "trivy_tpu_batch_occupancy_ratio", "histogram",
     "Real candidate pairs / padded dispatch rows, per device batch "
@@ -605,3 +620,35 @@ METRICS.declare(
     "bounds the max at --detect-tenant-max-share when more than one "
     "tenant is pending.",
     buckets=(0.05, 0.1, 0.25, 0.5, 0.75, 0.9, 1.0))
+METRICS.declare(
+    "trivy_tpu_spans_total", "counter",
+    "graftscope: finished spans by name (span=\"server.rpc\", "
+    "\"detect.assemble\", ...) — the span aggregate's count.")
+METRICS.declare(
+    "trivy_tpu_span_self_seconds_total", "counter",
+    "graftscope: span self wall time by name — each span's duration "
+    "less its children's on the same thread (a child on another "
+    "thread subtracts nothing).")
+METRICS.declare(
+    "trivy_tpu_span_self_cpu_seconds_total", "counter",
+    "graftscope: span self CPU time by name, from the CPU clock of the "
+    "thread that ran the span, less its same-thread children's; "
+    "process CPU minus the family's sum is the CPU no span covers.")
+METRICS.declare(
+    "trivy_tpu_detect_queue_wait_seconds", "histogram",
+    "detectd: time from a request's submit to the first merged "
+    "dispatch that carries it (the same clock read as its graftcost "
+    "queue-ms charge).",
+    buckets=(0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+             0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5))
+METRICS.declare(
+    "trivy_tpu_xla_programs_total", "counter",
+    "XLA programs this process obtained, by outcome "
+    "(outcome=\"loaded\" read from the persistent compilation cache, "
+    "\"compiled\" built by the compiler) — from JAX's compile and "
+    "cache-hit events; registered by compile_cache.enable().")
+METRICS.declare(
+    "trivy_tpu_xla_program_seconds_total", "counter",
+    "Wall seconds JAX spent obtaining XLA programs, by outcome "
+    "(outcome=\"loaded\"/\"compiled\", as "
+    "trivy_tpu_xla_programs_total).")

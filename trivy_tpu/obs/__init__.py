@@ -43,12 +43,12 @@ from .perf import LEDGER, PROF
 from .recorder import RECORDER
 from .slo import SLO
 from .trace import (COLLECTOR, add_attr, chrome_trace, current_span_id,
-                    current_trace_id, ensure_trace, new_trace,
-                    recording, span, write_chrome_trace)
+                    current_trace_id, ensure_trace, new_trace, span,
+                    write_chrome_trace)
 
 __all__ = [
     "COLLECTOR", "LEDGER", "PROF", "RECORDER", "SLO", "add_attr",
     "chrome_trace", "current_span_id", "current_trace_id",
     "device_status", "ensure_trace", "new_trace", "note_dispatch",
-    "recording", "span", "write_chrome_trace",
+    "span", "write_chrome_trace",
 ]

@@ -58,7 +58,7 @@ def _new_row() -> dict:
     return {
         "dispatches": 0, "warm_dispatches": 0,
         "real_rows": 0, "padded_rows": 0, "waste_bytes": 0,
-        "compiles": 0, "compile_ms": 0.0,
+        "compiles": 0, "compile_ms": 0.0, "loads": 0, "load_ms": 0.0,
         "hit_fill_sum": 0.0, "hit_fill_n": 0, "overflows": 0,
     }
 
@@ -122,16 +122,20 @@ class DispatchLedger:
                                 (padded - real) / padded, site=site)
 
     def note_compile(self, site: str, padded: int, h_cap: int,
-                     ms: float, warm: bool = False) -> None:
-        """First-dispatch-of-shape compile wall time (the launch call
-        that traced + lowered + compiled the new shape). The phase
-        label keeps warmup compiles distinguishable from the
-        mid-traffic ones a latency page cares about."""
+                     ms: float, warm: bool = False,
+                     loaded: bool = False) -> None:
+        """First-dispatch-of-shape wall time (the launch call that
+        traced + lowered the new shape and compiled it, or, `loaded`,
+        took its program from a cache: compile_cache.watch() saw no
+        compile). The phase label keeps warmup compiles
+        distinguishable from the mid-traffic ones a latency page
+        cares about."""
+        kind = "load" if loaded else "compile"
         with self._lock:
             row = self._shapes.setdefault((site, padded, h_cap),
                                           _new_row())
-            row["compiles"] += 1
-            row["compile_ms"] += ms
+            row[kind + "s"] += 1
+            row[kind + "_ms"] += ms
         METRICS.observe("trivy_tpu_device_compile_ms", ms,
                         phase="warmup" if warm else "traffic")
 
@@ -307,6 +311,8 @@ class DispatchLedger:
                 "warm_dispatches": r["warm_dispatches"],
                 "compiles": r["compiles"],
                 "compile_ms": round(r["compile_ms"], 3),
+                "loads": r["loads"],
+                "load_ms": round(r["load_ms"], 3),
                 "mean_occupancy": round(
                     r["real_rows"] / r["padded_rows"], 4)
                 if r["padded_rows"] else None,
@@ -345,6 +351,8 @@ class DispatchLedger:
             "compiles": sum(r["compiles"] for r in shapes),
             "compile_ms": round(sum(r["compile_ms"] for r in shapes),
                                 3),
+            "loads": sum(r["loads"] for r in shapes),
+            "load_ms": round(sum(r["load_ms"] for r in shapes), 3),
             "overflows": sum(r["overflows"] for r in shapes),
             "transfer_bytes": transfers,
             # graftcost: per-site device wall ms (launch+sync), the

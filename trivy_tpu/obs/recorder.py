@@ -67,6 +67,7 @@ def span_to_json(s) -> dict:
         "ts_unix": round(s.wall_start, 6),
         "dur_ms": round(s.dur * 1e3, 3),
         "cpu_ms": round(s.cpu * 1e3, 3),
+        "self_cpu_ms": round(s.self_cpu * 1e3, 3),
         "thread_id": s.thread_id,
         "attrs": attrs,
     }

@@ -14,11 +14,28 @@ Spans carry a trace id (stamped per scan / per RPC, propagated from
 client to server via the X-Trivy-Trace-Id header), a span id, their
 parent span id (contextvar nesting — correct across server handler
 threads; a remote parent forwarded via the X-Trivy-Parent-Span header
-links fragments across processes), wall + process time, and free-form
-attributes.
+links fragments across processes), wall time and the CPU time of the
+thread that ran them, and free-form attributes.
 
-Two sinks receive finished spans (graftwatch):
+Self time is a span's own share: its wall and CPU time less what its
+children on the SAME thread cover. A child on another thread (a
+detectd round under a request's context, an assemble-pool task)
+subtracts nothing — it runs beside its parent, not inside it. So the
+self CPU of every span a thread runs sums to that thread's CPU inside
+spans, and a process's CPU splits into per-span-name shares plus what
+no span covers.
 
+While a profiler session is active (the benchmark's `--trace 1`,
+/debug/profile, `--profile-dir`), each span also enters a
+`jax.profiler.TraceAnnotation` under its own name, so it lands on the
+profile's host plane on the device trace's clock.
+
+Three sinks receive finished spans (graftwatch):
+
+  * the span aggregate in METRICS — per span name, the count, self
+    seconds and self CPU seconds (`trivy_tpu_spans_total`,
+    `trivy_tpu_span_self_seconds_total`,
+    `trivy_tpu_span_self_cpu_seconds_total`), one lock per span;
   * the always-on flight recorder (obs/recorder.py) — a bounded
     lock-free ring every span lands in, serving /debug/traces and
     incident capture; its per-span cost is one counter bump and one
@@ -39,12 +56,15 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 import uuid
 
+from ..metrics import METRICS
 from .recorder import RECORDER
 
 # active span (for parent linkage) and active trace id; contextvars so
@@ -64,24 +84,96 @@ def _new_id(nbytes: int = 8) -> str:
     return uuid.uuid4().hex[:2 * nbytes]
 
 
+# span ids: a random per-process prefix plus a counter, 16 hex
+# characters — unique across the processes obs.collect stitches, at a
+# fraction of a uuid4's cost. A forked child draws a prefix of its own.
+_ID_PREFIX = ""
+_ID_COUNTER = itertools.count()
+
+
+def _reseed_ids() -> None:
+    global _ID_PREFIX, _ID_COUNTER
+    _ID_PREFIX = os.urandom(4).hex()
+    _ID_COUNTER = itertools.count()
+
+
+_reseed_ids()
+os.register_at_fork(after_in_child=_reseed_ids)
+
+
+def _span_id() -> str:
+    return f"{_ID_PREFIX}{next(_ID_COUNTER) & 0xFFFFFFFF:08x}"
+
+
 class Span:
     """One finished (or in-flight) timed region."""
 
     __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs",
-                 "wall_start", "start", "dur", "cpu", "thread_id")
+                 "wall_start", "start", "dur", "cpu", "thread_id",
+                 "child_dur", "child_cpu", "_parent", "_tok", "_ann")
 
     def __init__(self, name: str, trace_id: str, parent_id: str,
                  attrs: dict):
         self.name = name
         self.trace_id = trace_id
-        self.span_id = _new_id()
+        self.span_id = _span_id()
         self.parent_id = parent_id
         self.attrs = attrs
         self.wall_start = 0.0   # time.time() at enter
         self.start = 0.0        # perf_counter at enter
         self.dur = 0.0          # perf_counter seconds
-        self.cpu = 0.0          # process_time seconds
+        self.cpu = 0.0          # thread_time seconds
         self.thread_id = 0
+        # wall and CPU seconds of finished children on this thread
+        self.child_dur = 0.0
+        self.child_cpu = 0.0
+        # while open: the enclosing span, the context token, the
+        # profiler annotation
+        self._parent = self._tok = self._ann = None
+
+    def __enter__(self) -> "Span":
+        parent = self._parent = _SPAN.get()
+        self.trace_id = _TRACE.get()
+        self.parent_id = (parent.span_id if parent is not None
+                          else _REMOTE_PARENT.get())
+        self.thread_id = threading.get_ident()
+        ann = _annotation()
+        if ann is not None and ann.is_enabled():
+            self._ann = ann(self.name)
+            self._ann.__enter__()
+        self.wall_start = time.time()
+        self.cpu = time.thread_time()
+        self.start = time.perf_counter()
+        self._tok = _SPAN.set(self)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.dur = time.perf_counter() - self.start
+        self.cpu = time.thread_time() - self.cpu
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        _SPAN.reset(self._tok)
+        parent = self._parent
+        # a finished span in the ring keeps no chain of parents alive
+        self._parent = self._tok = self._ann = None
+        if parent is not None and parent.thread_id == self.thread_id:
+            parent.child_dur += self.dur
+            parent.child_cpu += self.cpu
+        METRICS.inc_many(((SPANS_TOTAL, 1.0),
+                          (SPAN_SELF, self.dur - self.child_dur),
+                          (SPAN_SELF_CPU, self.cpu - self.child_cpu)),
+                         span=self.name)
+        RECORDER.record_span(self)
+        COLLECTOR.record(self)
+        return False
+
+    @property
+    def self_dur(self) -> float:
+        return self.dur - self.child_dur
+
+    @property
+    def self_cpu(self) -> float:
+        return self.cpu - self.child_cpu
 
 
 class Collector:
@@ -97,15 +189,18 @@ class Collector:
         self._enabled = False
         self._limit = 200_000
         self._dropped = 0
+        self._base: dict = {}   # span_totals() when recording began
 
     @property
     def enabled(self) -> bool:
         return self._enabled
 
     def enable(self, limit: int | None = None) -> None:
+        base = span_totals()
         with self._lock:
             self._spans = []
             self._dropped = 0
+            self._base = base
             if limit is not None:
                 self._limit = limit
             self._enabled = True
@@ -138,27 +233,50 @@ class Collector:
             return out
 
     def phase_totals(self) -> dict[str, dict]:
-        """Aggregate recorded spans by name → {count, total_ms,
-        cpu_ms} (bench.py's per-phase breakdown)."""
+        """The span aggregate since recording began, by name →
+        {count, self_ms, self_cpu_ms} (bench.py's per-phase
+        breakdown)."""
+        with self._lock:
+            base = self._base
         out: dict[str, dict] = {}
-        for s in self.snapshot():
-            agg = out.setdefault(s.name,
-                                 {"count": 0, "total_ms": 0.0,
-                                  "cpu_ms": 0.0})
-            agg["count"] += 1
-            agg["total_ms"] += s.dur * 1e3
-            agg["cpu_ms"] += s.cpu * 1e3
-        for agg in out.values():
-            agg["total_ms"] = round(agg["total_ms"], 3)
-            agg["cpu_ms"] = round(agg["cpu_ms"], 3)
+        for name, (n, wall, cpu) in span_totals().items():
+            b = base.get(name, (0, 0.0, 0.0))
+            if n > b[0]:
+                out[name] = {"count": int(n - b[0]),
+                             "self_ms": round((wall - b[1]) * 1e3, 3),
+                             "self_cpu_ms": round((cpu - b[2]) * 1e3, 3)}
         return out
 
 
 COLLECTOR = Collector()
 
+SPANS_TOTAL = "trivy_tpu_spans_total"
+SPAN_SELF = "trivy_tpu_span_self_seconds_total"
+SPAN_SELF_CPU = "trivy_tpu_span_self_cpu_seconds_total"
 
-def recording() -> bool:
-    return COLLECTOR.enabled
+
+def span_totals() -> dict[str, tuple]:
+    """→ {span name: (count, self seconds, self CPU seconds)} since the
+    process started — the span aggregate /metrics exports."""
+    count = METRICS.family(SPANS_TOTAL)
+    wall = METRICS.family(SPAN_SELF)
+    cpu = METRICS.family(SPAN_SELF_CPU)
+    return {dict(labels)["span"]: (n, wall.get(labels, 0.0),
+                                   cpu.get(labels, 0.0))
+            for labels, n in count.items()}
+
+
+# jax.profiler.TraceAnnotation, looked up once jax is loaded (no
+# profiler session can be active before): None until then
+_ANNOTATION = None
+
+
+def _annotation():
+    global _ANNOTATION
+    if _ANNOTATION is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
 
 
 def current_trace_id() -> str:
@@ -203,31 +321,17 @@ def ensure_trace(trace_id: str | None = None):
         yield tid
 
 
-@contextlib.contextmanager
-def span(name: str, **attrs):
-    """Time a region; nests via contextvars. Yields the Span so callers
-    can attach attributes discovered mid-flight (`sp.attrs[...] = x`).
-    Every finished span lands in the always-on flight recorder's ring
-    (graftwatch); the COLLECTOR additionally keeps it only while
-    recording is enabled. A span with no local parent adopts the
-    remote parent id installed by new_trace(parent_id=...)."""
-    parent = _SPAN.get()
-    s = Span(name, _TRACE.get(),
-             parent.span_id if parent is not None
-             else _REMOTE_PARENT.get(), dict(attrs))
-    s.thread_id = threading.get_ident()
-    s.wall_start = time.time()
-    s.cpu = time.process_time()
-    s.start = time.perf_counter()
-    tok = _SPAN.set(s)
-    try:
-        yield s
-    finally:
-        s.dur = time.perf_counter() - s.start
-        s.cpu = time.process_time() - s.cpu
-        _SPAN.reset(tok)
-        RECORDER.record_span(s)
-        COLLECTOR.record(s)
+def span(name: str, **attrs) -> Span:
+    """Time a region: `with span("layer.phase", **attrs) as sp:`; nests
+    via contextvars, and `sp.attrs[...] = x` attaches attributes
+    discovered mid-flight. Every finished span lands in the always-on
+    flight recorder's ring (graftwatch); the COLLECTOR additionally
+    keeps it only while recording is enabled. A span with no local
+    parent adopts the remote parent id installed by
+    new_trace(parent_id=...). Its count, self time and self CPU go to
+    the span aggregate; under an active profiler session it is also a
+    TraceAnnotation."""
+    return Span(name, "", "", attrs)
 
 
 def add_attr(**attrs) -> None:
@@ -270,6 +374,7 @@ def chrome_trace(spans: list[Span] | None = None,
                 "span_id": s.span_id,
                 "parent_id": s.parent_id,
                 "cpu_ms": round(s.cpu * 1e3, 3),
+                "self_cpu_ms": round(s.self_cpu * 1e3, 3),
                 **s.attrs,
             },
         })

@@ -52,6 +52,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from .. import compile_cache
 from ..detect import feed as _feed
 from ..log import get as _get_logger
 from ..metrics import METRICS
@@ -286,11 +287,12 @@ def ledgered_sync_join(inner, run, site: str, real: int, t_total: int,
     if new_shape:
         failpoint("detect.compile")
         with span("detect.compile", t_pad=t_total, h_cap=h_cap,
-                  **span_attrs):
+                  **span_attrs), compile_cache.watch() as got:
             t0 = time.perf_counter()
             out = run()
             compile_ms = (time.perf_counter() - t0) * 1e3
-        LEDGER.note_compile(site, t_total, h_cap, compile_ms)
+        LEDGER.note_compile(site, t_total, h_cap, compile_ms,
+                            loaded=got.compiled == 0)
     else:
         out = run()
     _cost.charge_device_ms(site, (time.perf_counter() - t_run) * 1e3,

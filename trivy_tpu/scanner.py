@@ -22,7 +22,7 @@ from .detect.langpkg import LangpkgScanner
 from .detect.ospkg import OspkgScanner
 from .fanal.applier import apply_layers
 from .obs import cost as _cost
-from .obs import ensure_trace, recording, span
+from .obs import ensure_trace, span
 
 if TYPE_CHECKING:
     from .detect.sched import SchedOptions
@@ -197,9 +197,8 @@ class LocalScanner:
 
         # phase 2: one pipelined dispatch across all live targets
         # (device). Server mode routes through detectd so concurrent
-        # requests coalesce; under graftscope recording the direct
-        # path runs instead — its fenced stages keep phase attribution
-        # exact (the scheduler's threads would scatter the spans).
+        # requests coalesce, recorded or not: a trace times the
+        # program that serves.
         hit_lists: list = [replayed.get(i) for i in range(len(batches))]
         live = [i for i in range(len(batches)) if i not in replayed]
         if live:
@@ -210,7 +209,7 @@ class LocalScanner:
                 # takes the direct path too: merging its queries into
                 # a live detectd dispatch would make live traffic
                 # share fate — and breaker charges — with guest work
-                if self.sched is not None and not recording() \
+                if self.sched is not None \
                         and not GUARD.blameless_active():
                     live_hits = self.sched.detect_many(live_batches)
                 else:

@@ -44,6 +44,7 @@ from typing import NamedTuple, Optional
 
 import numpy as np
 
+from .. import compile_cache
 from .. import types as T
 from ..metrics import METRICS
 from ..obs import cost as _cost
@@ -281,18 +282,20 @@ class SecretScanner:
                 with self._lock:
                     new_shape = piece.shape not in self._seen_shapes
                     self._seen_shapes.add(piece.shape)
-                if self.mesh is not None:
-                    from ..parallel.mesh import sharded_shiftor_scan
-                    futures.append(sharded_shiftor_scan(
-                        self.mesh, kw_words, kw_masks, piece,
-                        n_words=bank.words))
-                else:
-                    futures.append(ac.shiftor_scan(
-                        kw_words, kw_masks, jax.device_put(piece),
-                        n_words=bank.words))
+                with compile_cache.watch() as got:
+                    if self.mesh is not None:
+                        from ..parallel.mesh import sharded_shiftor_scan
+                        futures.append(sharded_shiftor_scan(
+                            self.mesh, kw_words, kw_masks, piece,
+                            n_words=bank.words))
+                    else:
+                        futures.append(ac.shiftor_scan(
+                            kw_words, kw_masks, jax.device_put(piece),
+                            n_words=bank.words))
                 if new_shape:
                     LEDGER.note_compile(
-                        site, b, 0, (time.perf_counter() - t0) * 1e3)
+                        site, b, 0, (time.perf_counter() - t0) * 1e3,
+                        loaded=got.compiled == 0)
             LEDGER.note_dispatch(site, real_rows, b,
                                  row_bytes=row_len)
             # graftcost: the dispatch call's wall time (compile
@@ -495,11 +498,14 @@ def _compiled_shiftor(state_words: int, n_words: int,
     from ..ops import shiftor_pallas as sp
     t0 = time.perf_counter()
     kw = jax.ShapeDtypeStruct((state_words, sp.K_LANES), np.int32)
-    exe = sp.shiftor.lower(
-        kw, kw, jax.ShapeDtypeStruct((1, sp.K_LANES), np.int32),
-        jax.ShapeDtypeStruct(shape, np.uint8), n_words=n_words).compile()
+    with compile_cache.watch() as got:
+        exe = sp.shiftor.lower(
+            kw, kw, jax.ShapeDtypeStruct((1, sp.K_LANES), np.int32),
+            jax.ShapeDtypeStruct(shape, np.uint8),
+            n_words=n_words).compile()
     LEDGER.note_compile("secret", shape[0], 0,
-                        (time.perf_counter() - t0) * 1e3)
+                        (time.perf_counter() - t0) * 1e3,
+                        loaded=got.compiled == 0)
     return exe
 
 

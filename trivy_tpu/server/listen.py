@@ -535,9 +535,15 @@ class Handler(BaseHTTPRequestHandler):
         pass
 
     def _json(self, code: int, payload: dict):
-        body = json.dumps(payload).encode()
+        with span("server.encode"):
+            self._send(code, "application/json",
+                       json.dumps(payload).encode())
+
+    def _send(self, code: int, ctype: str, body: bytes):
+        """Headers, then the body: the one writer of POST replies and
+        JSON GET replies."""
         self.send_response(code)
-        self.send_header("Content-Type", "application/json")
+        self.send_header("Content-Type", ctype)
         self.send_header("Content-Length", str(len(body)))
         if self._trace_id:
             self.send_header(TRACE_HEADER, self._trace_id)
@@ -697,19 +703,9 @@ class Handler(BaseHTTPRequestHandler):
 
     def _proto(self, code: int, payload: dict, desc: str):
         from .protowire import encode_msg
-        body = encode_msg(payload, desc)
-        self.send_response(code)
-        self.send_header("Content-Type", "application/protobuf")
-        self.send_header("Content-Length", str(len(body)))
-        if self._trace_id:
-            self.send_header(TRACE_HEADER, self._trace_id)
-        if self._db_version:
-            self.send_header(DB_VERSION_HEADER, self._db_version)
-        led = _cost.active()
-        if led is not None:
-            self.send_header(COST_HEADER, led.header_json())
-        self.end_headers()
-        self.wfile.write(body)
+        with span("server.encode"):
+            self._send(code, "application/protobuf",
+                       encode_msg(payload, desc))
 
     def _reply(self, payload: dict, desc: str):
         """Encode the response in the request's encoding (Twirp
@@ -764,19 +760,20 @@ class Handler(BaseHTTPRequestHandler):
         self._is_proto = ctype in ("application/protobuf",
                                    "application/x-protobuf")
         route = self.path
+        desc = self._ROUTES.get(route)
         try:
-            length = int(self.headers.get("Content-Length", "0"))
-            body = self.rfile.read(length)
-            if self._is_proto:
-                from .protowire import decode_msg
-                desc = self._ROUTES.get(route)
-                if desc is None:
-                    return self._twirp_error(404, "bad_route", route)
-                req = decode_msg(body, desc)
-            else:
-                req = json.loads(body or b"{}")
+            with span("server.decode"):
+                length = int(self.headers.get("Content-Length", "0"))
+                body = self.rfile.read(length)
+                if not self._is_proto:
+                    req = json.loads(body or b"{}")
+                elif desc is not None:
+                    from .protowire import decode_msg
+                    req = decode_msg(body, desc)
         except (ValueError, json.JSONDecodeError):
             return self._twirp_error(400, "malformed", "bad body")
+        if self._is_proto and desc is None:
+            return self._twirp_error(404, "bad_route", route)
 
         try:
             if route == "/twirp/trivy.scanner.v1.Scanner/Scan":
@@ -788,11 +785,12 @@ class Handler(BaseHTTPRequestHandler):
                                       req.get("artifact_info") or {})
                 return self._reply({}, "Empty")
             if route == "/twirp/trivy.cache.v1.Cache/PutBlob":
-                blob_j = req.get("blob_info") or {}
-                if self._is_proto:
-                    from .convert import proto_blob_to_json
-                    blob_j = proto_blob_to_json(blob_j)
-                blob = blob_from_json(blob_j)
+                with span("server.decode"):
+                    blob_j = req.get("blob_info") or {}
+                    if self._is_proto:
+                        from .convert import proto_blob_to_json
+                        blob_j = proto_blob_to_json(blob_j)
+                    blob = blob_from_json(blob_j)
                 st.cache.put_blob(req.get("diff_id", ""), blob)
                 return self._reply({}, "Empty")
             if route == "/twirp/trivy.cache.v1.Cache/MissingBlobs":
@@ -880,7 +878,8 @@ class Handler(BaseHTTPRequestHandler):
         # Charged on the shed path too (the wait really happened)
         t_adm = time.perf_counter()
         try:
-            st.admission.admit(deadline, tenant=qlabel)
+            with span("server.admission"):
+                st.admission.admit(deadline, tenant=qlabel)
         except Shed as s:
             _cost.charge_queue_ms(
                 (time.perf_counter() - t_adm) * 1e3, ledger=led)
@@ -975,15 +974,19 @@ class Handler(BaseHTTPRequestHandler):
             if led is not None else None)
         _log.debug("scan %s: %d results in %.1fms",
                    req.get("target", ""), len(results), elapsed * 1e3)
-        if self._is_proto:
-            from .convert import results_to_proto
-            return self._proto(200, results_to_proto(results, os_info),
-                               "ScanResponse")
-        self._json(200, {
-            "os": {"family": os_info.family, "name": os_info.name,
-                   "eosl": os_info.eosl},
-            "results": [_result_to_json(r) for r in results],
-        })
+        # one server.encode span: the response build, its bytes and
+        # the write
+        with span("server.encode"):
+            if self._is_proto:
+                from .convert import results_to_proto
+                from .protowire import encode_msg
+                return self._send(200, "application/protobuf", encode_msg(
+                    results_to_proto(results, os_info), "ScanResponse"))
+            self._send(200, "application/json", json.dumps({
+                "os": {"family": os_info.family, "name": os_info.name,
+                       "eosl": os_info.eosl},
+                "results": [_result_to_json(r) for r in results],
+            }).encode())
 
 
 def drain_then_shutdown(httpd, state: ServerState,
