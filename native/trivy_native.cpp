@@ -7,12 +7,13 @@
 //
 //   - fnv1a64_batch: join-key hashing for package/advisory batches
 //     (pkg/detector's per-package bucket lookups become hash-join keys);
-//   - lower_pack_chunks: lowercasing + fixed-size overlapped chunking of
-//     secret-scan candidate files into the [B, L] uint8 tensors the
-//     device Aho-Corasick prefilter consumes (the reference lowercases
-//     per rule per file, pkg/fanal/secret/scanner.go:170).
+//   - lower_pack_batch: lowercasing + fixed-size overlapped chunking of
+//     every secret-scan candidate file of a call into one [B, L] uint8
+//     tensor for the device shift-or prefilter, with a 64-bit
+//     fingerprint of each row for the engine's dedup (the reference
+//     lowercases per rule per file, pkg/fanal/secret/scanner.go:170).
 //
-// Build: g++ -O3 -march=native -shared -fPIC (driven by the Python
+// Build: g++ -O3 -shared -fPIC -std=c++17 (driven by the Python
 // loader; no external dependencies).
 
 #include <cstdint>
@@ -38,39 +39,51 @@ void fnv1a64_batch(const uint8_t* data, const int64_t* offsets, int64_t n,
     }
 }
 
-// Lowercase `len` bytes of `data` and pack them into chunks of
-// `chunk_len` with `overlap` bytes of overlap (stride chunk_len -
-// overlap), zero-padding the tail. `out` must hold max_chunks*chunk_len
-// bytes. Returns the number of chunks written via n_chunks.
-void lower_pack_chunks(const uint8_t* data, int64_t len, int32_t chunk_len,
-                       int32_t overlap, uint8_t* out, int32_t max_chunks,
-                       int32_t* n_chunks) {
-    int32_t stride = chunk_len - overlap;
+// Lowercase and chunk n_files files (file i is data[i], lens[i] bytes,
+// rows[i] rows) into `out`, row after row: row r of a file holds its
+// bytes [r * stride, r * stride + chunk_len) with stride chunk_len -
+// overlap (clamped to 1), zero past the file's end. The caller sizes
+// `out` (and fp) for the sum of rows. When fp is not null, fp[row] is
+// the row's fingerprint: the sum over its little-endian 64-bit words
+// w_j of mix((w_j + j * G) * C), mix(x) = x ^ (x >> 31), modulo 2^64
+// (chunk_len a multiple of 8). Equal rows have equal fingerprints; the
+// caller compares the bytes of rows that share one.
+void lower_pack_batch(const uint8_t* const* data, const int64_t* lens,
+                      const int64_t* rows, int64_t n_files,
+                      int32_t chunk_len, int32_t overlap, uint8_t* out,
+                      uint64_t* fp) {
+    const int64_t L = chunk_len;
+    int64_t stride = L - overlap;
     if (stride < 1) stride = 1;
-    int32_t count = 0;
-    for (int64_t off = 0; off < len && count < max_chunks; off += stride) {
-        // Skip the final stride only when the previous chunk really
-        // covers the remaining tail: it spans [off - stride, off -
-        // stride + chunk_len), which reaches chunk_len - stride past
-        // `off` — equal to `overlap` only while the stride is
-        // unclamped. The old `len - off <= overlap` test dropped the
-        // uncovered tail of multi-chunk files when overlap >=
-        // chunk_len clamped the stride to 1.
-        if (off > 0 && len - off <= chunk_len - stride) break;
-        int64_t piece = len - off;
-        if (piece > chunk_len) piece = chunk_len;
-        uint8_t* dst = out + static_cast<int64_t>(count) * chunk_len;
-        for (int64_t j = 0; j < piece; ++j) {
-            uint8_t c = data[off + j];
-            dst[j] = (c >= 'A' && c <= 'Z') ? c + 32 : c;
+    const uint64_t kG = 0x9E3779B97F4A7C15ULL;
+    const uint64_t kC = 0xBF58476D1CE4E5B9ULL;
+    int64_t row = 0;
+    for (int64_t f = 0; f < n_files; ++f) {
+        for (int64_t r = 0; r < rows[f]; ++r, ++row) {
+            const int64_t off = r * stride;
+            int64_t piece = lens[f] - off;
+            if (piece > L) piece = L;
+            if (piece < 0) piece = 0;
+            const uint8_t* src = data[f] + off;
+            uint8_t* dst = out + row * L;
+            for (int64_t j = 0; j < piece; ++j) {
+                uint8_t c = src[j];
+                dst[j] = (c >= 'A' && c <= 'Z') ? c + 32 : c;
+            }
+            if (piece < L) memset(dst + piece, 0, L - piece);
+            if (fp != nullptr) {
+                // the row is still in cache: one more pass over it
+                uint64_t h = 0, k = 0;
+                for (int64_t j = 0; j < L; j += 8, k += kG) {
+                    uint64_t w;
+                    memcpy(&w, dst + j, 8);
+                    uint64_t x = (w + k) * kC;
+                    h += x ^ (x >> 31);
+                }
+                fp[row] = h;
+            }
         }
-        if (piece < chunk_len) {
-            memset(dst + piece, 0, chunk_len - piece);
-        }
-        ++count;
-        if (off + chunk_len >= len) break;
     }
-    *n_chunks = count;
 }
 
 // Case-insensitive memmem over a haystack for the host prefilter

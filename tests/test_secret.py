@@ -174,3 +174,32 @@ class TestShiftorScan:
         out = device_scanner.scan_files(files)
         assert len(out) == 5
         assert all(s.findings[0].rule_id == "github-pat" for s in out)
+
+
+def test_secret_pack_cpu_ms_reader():
+    """The benchmark's `secret_pack_cpu_ms` is the self CPU of the
+    `secret.pack` span per completed scan; a program without that span
+    reads nothing."""
+    import os
+    from types import SimpleNamespace
+
+    from benchmark.spec import Bench
+
+    bench = Bench(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    r = bench.reader("secret_pack_cpu_ms")
+    assert "secret_pack_cpu_ms" in {
+        m["name"] for m in bench.metrics("image-alpine-secret.sweep",
+                                         "per_layer")}
+    before = {"spans": {"secret.pack": 1.0, "secret.prefilter": 4.0,
+                        "fanal.walk_tar": 2.0}, "process_cpu": 10.0}
+    after = {"spans": {"secret.pack": 1.5, "secret.prefilter": 9.0,
+                       "fanal.walk_tar": 7.0}, "process_cpu": 30.0}
+    ctx = SimpleNamespace(scans=[None] * 10)
+    assert r.read(ctx, before, after) == pytest.approx(50.0)
+    # the span's first run inside the window
+    assert r.read(ctx, {"spans": {}, "process_cpu": 0.0},
+                  after) == pytest.approx(150.0)
+    parent = {"spans": {"secret.prefilter": 9.0}, "process_cpu": 30.0}
+    assert r.read(ctx, before, parent) is None
+    assert r.read(ctx, None, None) is None
+    assert r.read(SimpleNamespace(scans=[]), before, after) is None

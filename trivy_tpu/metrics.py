@@ -564,6 +564,12 @@ METRICS.declare(
     "kernel, jnp = ac.shiftor_scan (CPU and mesh), host = small "
     "batches, open-breaker fallback, and device errors.")
 METRICS.declare(
+    "trivy_tpu_secret_prefilter_rows_total", "counter",
+    "16 KiB chunk rows the device keyword prefilter packed, by "
+    "outcome=\"unique\" (shipped to the device) or \"duplicate\" "
+    "(bytes equal to an earlier row of the same launch, which takes "
+    "that row's result) — how often the exact row dedup engages.")
+METRICS.declare(
     "trivy_tpu_secret_scan_bytes_total", "counter",
     "Bytes through the keyword prefilter, by serving path "
     "(path=\"pallas\"/\"jnp\"/\"host\") — the MB/s numerator for each "

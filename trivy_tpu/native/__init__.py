@@ -47,10 +47,11 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
         lib.fnv1a64_batch.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
             ctypes.c_void_p]
-        lib.lower_pack_chunks.argtypes = [
-            ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32,
-            ctypes.c_int32, ctypes.c_void_p, ctypes.c_int32,
-            ctypes.c_void_p]
+        lib.lower_pack_batch.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+            ctypes.c_int64, ctypes.c_int32, ctypes.c_int32,
+            ctypes.c_void_p, ctypes.c_void_p]
+        lib.lower_pack_batch.restype = None
         lib.contains_lower.argtypes = [
             ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
             ctypes.c_int64]
@@ -83,23 +84,39 @@ def fnv1a64_batch(keys: list[bytes]) -> np.ndarray:
     return out
 
 
-def lower_pack_chunks(data: bytes, chunk_len: int,
-                      overlap: int) -> Optional[np.ndarray]:
-    """Lowercase + chunk one file → uint8[n_chunks, chunk_len]; None if
-    the native library is unavailable (caller falls back)."""
+def lower_pack_batch(files: list[bytes], counts: np.ndarray,
+                     chunk_len: int, overlap: int, out: np.ndarray,
+                     fp: Optional[np.ndarray] = None) -> bool:
+    """Lowercase + chunk every file, row after row, into `out`
+    (uint8[rows, chunk_len], C-contiguous): file i makes counts[i] rows
+    (ops.ac.chunk_counts); rows past their sum are left as they are.
+    When `fp` is given (uint64, one per row written), each row's
+    fingerprint (see native/trivy_native.cpp). → False if the native
+    library is unavailable (caller falls back)."""
     lib = _build_and_load()
     if lib is None:
-        return None
-    if not data:
-        return np.zeros((0, chunk_len), np.uint8)
-    stride = max(1, chunk_len - overlap)
-    max_chunks = (len(data) + stride - 1) // stride + 1
-    out = np.zeros((max_chunks, chunk_len), dtype=np.uint8)
-    n = ctypes.c_int32(0)
-    buf = np.frombuffer(data, dtype=np.uint8)
-    lib.lower_pack_chunks(
-        buf.ctypes.data, ctypes.c_int64(len(data)),
-        ctypes.c_int32(chunk_len), ctypes.c_int32(overlap),
-        out.ctypes.data, ctypes.c_int32(max_chunks),
-        ctypes.byref(n))
-    return out[:n.value]
+        return False
+    counts = np.ascontiguousarray(counts, np.int64)
+    total = int(counts.sum())
+    if counts.shape != (len(files),) or (counts < 0).any():
+        raise ValueError(f"counts must be {len(files)} row counts ≥ 0")
+    if out.dtype != np.uint8 or out.ndim != 2 or \
+            out.shape[1] != chunk_len or not out.flags.c_contiguous or \
+            out.shape[0] < total:
+        raise ValueError(f"out must be C-contiguous uint8[≥{total}, "
+                         f"{chunk_len}], got {out.dtype}{out.shape}")
+    if fp is not None and (fp.dtype != np.uint64 or fp.ndim != 1 or
+                           fp.shape[0] < total or chunk_len % 8):
+        raise ValueError(f"fp must be uint64[≥{total}] and chunk_len a "
+                         f"multiple of 8")
+    # c_char_p points at each bytes object's own buffer (no copy);
+    # `data` holds them across the call
+    data = [f if type(f) is bytes else bytes(f) for f in files]
+    ptrs = (ctypes.c_char_p * max(1, len(data)))(*data)
+    lens = np.fromiter((len(f) for f in data), np.int64, len(data))
+    lib.lower_pack_batch(
+        ptrs, lens.ctypes.data, counts.ctypes.data,
+        ctypes.c_int64(len(data)), ctypes.c_int32(chunk_len),
+        ctypes.c_int32(overlap), out.ctypes.data,
+        None if fp is None else fp.ctypes.data)
+    return True

@@ -155,50 +155,89 @@ def shiftor_scan(kw_words, kw_masks, chunks, *, n_words: int):
     return acc
 
 
+def chunk_counts(lengths, chunk_len: int, overlap: int) -> np.ndarray:
+    """Rows each file of `lengths` bytes packs into: one for a non-empty
+    file of up to chunk_len bytes, then one per stride (chunk_len -
+    overlap, at least 1) until a row reaches the file's end. Row r
+    starts at r·stride, so any overlap + 1 consecutive bytes lie wholly
+    inside some row — with a clamped stride (overlap ≥ chunk_len) too."""
+    n = np.asarray(lengths, np.int64)
+    stride = max(1, chunk_len - overlap)
+    more = np.maximum(0, -(-(n - chunk_len) // stride))
+    return np.where(n > 0, 1 + more, 0)
+
+
+def pack_batch(files: list[bytes], chunk_len: int, overlap: int,
+               capacity=None, fingerprint: bool = False
+               ) -> tuple[np.ndarray, np.ndarray, np.ndarray | None]:
+    """Lowercase and chunk every file, row after row, into one buffer
+    in one pass → (rows uint8[capacity(n), chunk_len], owner int64[n]:
+    row → file index, fp uint64[n] or None), n the rows the files make
+    (chunk_counts). Row r of a file holds its bytes [r·stride, r·stride
+    + chunk_len), zero past the file's end; rows from n on are zero
+    (capacity: n → buffer rows, default n). The native packer
+    (trivy_tpu.native) fills the buffer when the library builds, numpy
+    otherwise, identically; `fingerprint` (chunk_len a multiple of 8)
+    adds each row's row_fingerprints value, from the native packer
+    while the row is still in cache."""
+    from .. import native
+    counts = chunk_counts([len(f) for f in files], chunk_len, overlap)
+    n = int(counts.sum())
+    rows = np.zeros((n if capacity is None else capacity(n), chunk_len),
+                    np.uint8)
+    fp = np.empty(n, np.uint64) if fingerprint else None
+    if not native.lower_pack_batch(files, counts, chunk_len, overlap,
+                                   rows, fp):
+        _pack_py(files, counts, chunk_len, overlap, rows)
+        if fingerprint:
+            fp = row_fingerprints(rows[:n])
+    return rows, np.repeat(np.arange(len(files), dtype=np.int64),
+                           counts), fp
+
+
 def pack_chunks(files: list[bytes], chunk_len: int,
                 overlap: int) -> tuple[np.ndarray, np.ndarray]:
     """Pack lowercased file bytes into [B, chunk_len] with per-chunk file
-    index map [B]. Stride = chunk_len - overlap. Uses the native C++
-    packer when available (trivy_tpu.native)."""
-    from ..native import lower_pack_chunks
-    blocks, owner = [], []
-    native_ok = True
-    for fi, data in enumerate(files):
-        if not data:
-            continue
-        block = lower_pack_chunks(data, chunk_len, overlap) \
-            if native_ok else None
-        if block is None:
-            native_ok = False
-            block = _pack_one_py(data, chunk_len, overlap)
-        if block.shape[0]:
-            blocks.append(block)
-            owner.extend([fi] * block.shape[0])
-    if not blocks:
-        return (np.zeros((0, chunk_len), np.uint8), np.zeros(0, np.int64))
-    return np.concatenate(blocks, axis=0), np.asarray(owner)
+    index map [B] (pack_batch without padding or fingerprints)."""
+    rows, owner, _ = pack_batch(files, chunk_len, overlap)
+    return rows, owner
+
+
+# row fingerprint: Σ_j mix((w_j + j·G) · C) mod 2^64 over a row's
+# little-endian 64-bit words, mix(x) = x ^ (x >> 31) — the same sum
+# native/trivy_native.cpp lower_pack_batch computes
+_FP_G = np.uint64(0x9E3779B97F4A7C15)
+_FP_C = np.uint64(0xBF58476D1CE4E5B9)
+
+
+def row_fingerprints(rows: np.ndarray) -> np.ndarray:
+    """uint8[n, L] (L a multiple of 8, rows contiguous) → uint64[n]: a
+    64-bit fingerprint per row. Equal rows give equal fingerprints;
+    unequal rows almost never do, and callers compare the bytes of rows
+    that share one."""
+    words = np.ascontiguousarray(rows).view(np.uint64)
+    x = words + np.arange(words.shape[1], dtype=np.uint64) * _FP_G
+    x *= _FP_C
+    x ^= x >> np.uint64(31)
+    return x.sum(axis=1, dtype=np.uint64)
+
+
+def _pack_py(files: list[bytes], counts: np.ndarray, chunk_len: int,
+             overlap: int, out: np.ndarray) -> None:
+    """numpy twin of the native packer: rows of `files` into `out`."""
+    stride = max(1, chunk_len - overlap)
+    row = 0
+    for data, n_rows in zip(files, counts.tolist()):
+        src = np.frombuffer(data, np.uint8)
+        for r in range(n_rows):
+            piece = src[r * stride:r * stride + chunk_len]
+            out[row, :len(piece)] = _LOWER[piece]
+            out[row, len(piece):] = 0
+            row += 1
 
 
 def _pack_one_py(data: bytes, chunk_len: int, overlap: int) -> np.ndarray:
-    stride = max(1, chunk_len - overlap)
-    arr = lower_bytes(data)
-    rows = []
-    for off in range(0, len(arr), stride):
-        # skip the final stride only when the previous chunk really
-        # covers the whole remaining tail. The previous chunk spans
-        # [off - stride, off - stride + chunk_len); when the stride is
-        # clamped (overlap ≥ chunk_len) that reaches only chunk_len -
-        # stride past `off`, NOT `overlap` past it — the old
-        # `len(piece) <= overlap` test dropped the uncovered tail of
-        # any multi-chunk file in that regime.
-        if off > 0 and len(arr) - off <= chunk_len - stride:
-            break  # fully covered by the previous chunk
-        piece = arr[off:off + chunk_len]
-        row = np.zeros(chunk_len, dtype=np.uint8)
-        row[:len(piece)] = piece
-        rows.append(row)
-        if off + chunk_len >= len(arr):
-            break
-    if not rows:
-        return np.zeros((0, chunk_len), np.uint8)
-    return np.stack(rows)
+    counts = chunk_counts([len(data)], chunk_len, overlap)
+    out = np.zeros((int(counts[0]), chunk_len), np.uint8)
+    _pack_py([data], counts, chunk_len, overlap, out)
+    return out

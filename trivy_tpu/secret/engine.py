@@ -53,8 +53,9 @@ from ..ops import ac
 from .rules import BUILTIN_RULES, GLOBAL_ALLOW_RULES, Rule
 
 CHUNK_LEN = 16384
-# Max chunk rows per device call (64 MiB of chunk bytes), padded to a
-# power of two so each bucket shape compiles exactly once. The Pallas
+# Max chunk rows per device call (64 MiB of chunk bytes), a power of
+# two; a launch's last call is padded up to a power of two too, so each
+# bucket shape compiles exactly once (see _pieces). The Pallas
 # path's state-word planes make the top bucket's temporaries ~3.8 GB
 # on a v5e (compiled memory_analysis, PR 21).
 DEVICE_ROWS = 4096
@@ -190,50 +191,41 @@ class SecretScanner:
         return self._launch(self._pack(files))
 
     def _pack(self, files: list[bytes]) -> "_Packed":
-        """Host half of a device prefilter: chunk, dedup, and compile
-        the Pallas kernel for every bucket shape the launch will use."""
-        import hashlib
-
-        from ..ops import next_pow2
+        """Host half of a device prefilter: chunk every file into one
+        buffer in one pass, dedup its rows exactly, and compile the
+        Pallas kernel for every bucket shape the launch will use."""
         bank = self._bank
-        overlap = bank.max_kw_len - 1
-        chunks, owner = ac.pack_chunks(files, CHUNK_LEN, overlap)
-        # content-addressed dedup: container filesystems repeat whole
-        # blocks across files/layers (vendored code, copied configs,
-        # near-identical images), and the host→device link is the scan
-        # bottleneck — ship each distinct 16 KiB chunk once and fan the
-        # result back out. Hashing is ~2 GB/s, pure win.
-        seen: dict[bytes, int] = {}
-        remap = np.empty(chunks.shape[0], np.int64)
-        uniq_rows: list[int] = []
-        for i in range(chunks.shape[0]):
-            h = hashlib.blake2b(chunks[i], digest_size=16).digest()
-            j = seen.get(h)
-            if j is None:
-                j = seen[h] = len(uniq_rows)
-                uniq_rows.append(i)
-            remap[i] = j
-        uniq = chunks[np.asarray(uniq_rows)] \
-            if len(uniq_rows) < chunks.shape[0] else chunks
-        # bounded rows per device call (O(B·L) working set), padded to a
-        # power of two so each bucket shape compiles once
-        pieces = []
-        for off in range(0, uniq.shape[0], DEVICE_ROWS):
-            piece = uniq[off:off + DEVICE_ROWS]
-            real_rows = int(piece.shape[0])
-            b = next_pow2(real_rows, floor=64)
-            if real_rows < b:
-                pad = np.zeros((b, piece.shape[1]), np.uint8)
-                pad[:real_rows] = piece
-                piece = pad
-            pieces.append((piece, real_rows))
+        with span("secret.pack", files=len(files)) as sp:
+            rows, owner, fp = ac.pack_batch(
+                files, CHUNK_LEN, bank.max_kw_len - 1,
+                capacity=_bucket_rows, fingerprint=True)
+            n = int(owner.shape[0])
+            # content dedup: container filesystems repeat whole blocks
+            # across files and layers (vendored code, copied configs,
+            # near-identical images) — each distinct 16 KiB row ships
+            # once and its result fans back out. Without a repeat the
+            # packed buffer ships as it is.
+            remap, uniq = _dedup(rows[:n], fp)
+            n_uniq = int(uniq.shape[0])
+            if n_uniq < n:
+                out = np.zeros((_bucket_rows(n_uniq), CHUNK_LEN), np.uint8)
+                np.take(rows, uniq, axis=0, out=out[:n_uniq])
+                rows = out
+            METRICS.inc("trivy_tpu_secret_prefilter_rows_total", n_uniq,
+                        outcome="unique")
+            METRICS.inc("trivy_tpu_secret_prefilter_rows_total",
+                        n - n_uniq, outcome="duplicate")
+            sp.attrs["rows"] = n
+            sp.attrs["unique"] = n_uniq
+        pieces = [(rows[off:off + b], real)
+                  for off, real, b in _pieces(n_uniq)]
         use_pallas = (self.mesh is None and bank.n_keywords <= 128
                       and _tpu_backend())
         if use_pallas:
             for piece, _ in pieces:
                 self._pallas_kernel(piece.shape)
-        return _Packed(len(files), owner, remap, int(uniq.shape[0]),
-                       pieces, use_pallas)
+        return _Packed(len(files), owner, remap, n_uniq, pieces,
+                       use_pallas)
 
     def _launch(self, packed: "_Packed") -> tuple[list[set[int]], str]:
         """Device half: dispatch every piece, fetch, decode. The path
@@ -512,6 +504,55 @@ def _compiled_shiftor(state_words: int, n_words: int,
 def _tpu_backend() -> bool:
     import jax
     return jax.default_backend() == "tpu"
+
+
+def _pieces(n: int) -> list[tuple[int, int, int]]:
+    """→ [(first row, real rows, bucket rows)] of the device calls for
+    n packed rows: at most DEVICE_ROWS (a power of two) per call, the
+    last padded up to a power of two so each bucket shape compiles
+    once. The buckets lie back to back, so a buffer of _bucket_rows(n)
+    rows holds every piece as a view."""
+    from ..ops import next_pow2
+    return [(off, min(DEVICE_ROWS, n - off),
+             next_pow2(min(DEVICE_ROWS, n - off), floor=64))
+            for off in range(0, n, DEVICE_ROWS)]
+
+
+def _bucket_rows(n: int) -> int:
+    pieces = _pieces(n)
+    return pieces[-1][0] + pieces[-1][2] if pieces else 0
+
+
+def _dedup(rows: np.ndarray, fp: np.ndarray
+           ) -> tuple[np.ndarray, np.ndarray]:
+    """Exact row dedup → (remap: row → index into uniq, uniq: the first
+    row of each distinct content, ascending). Rows are grouped by their
+    64-bit fingerprint and only rows that share one are byte-compared,
+    so two rows merge only when their bytes are equal."""
+    n = fp.shape[0]
+    order = np.argsort(fp, kind="stable")
+    shared = fp[order[1:]] == fp[order[:-1]]
+    if not shared.any():
+        return np.arange(n), np.arange(n)
+    first = np.arange(n)             # row → first row with equal bytes
+    starts = np.flatnonzero(np.r_[True, ~shared])
+    ends = np.r_[starts[1:], n]
+    multi = ends - starts > 1
+    for s, e in zip(starts[multi].tolist(), ends[multi].tolist()):
+        group = order[s:e]           # ascending: the sort is stable
+        head, rest = group[0], group[1:]
+        # compared 256 rows at a time: a file of one repeated block
+        # can fill a whole group
+        if all((rows[rest[k:k + 256]] == rows[head]).all()
+               for k in range(0, rest.shape[0], 256)):
+            first[rest] = head
+            continue
+        # a fingerprint shared by rows that differ: key them by bytes
+        seen: dict[bytes, int] = {}
+        for i in group.tolist():
+            first[i] = seen.setdefault(rows[i].tobytes(), i)
+    is_first = first == np.arange(n)
+    return (np.cumsum(is_first) - 1)[first], np.flatnonzero(is_first)
 
 
 class _Packed(NamedTuple):
