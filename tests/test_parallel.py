@@ -402,6 +402,138 @@ def test_scheduler_routes_over_mesh(table, _clean_guard):
     assert results == expected
 
 
+# ---- the mesh dispatch's spans and counters ------------------------------
+
+MESH_SPANS = {"detect.mesh.probe", "detect.mesh.partition",
+              "detect.mesh.join", "detect.mesh.scatter"}
+
+
+def _mesh_counters() -> dict:
+    return {p: METRICS.get("trivy_tpu_mesh_dispatches_total", path=p)
+            for p in ("device", "host")}
+
+
+def _since(before: dict) -> dict:
+    return {k: v - before[k] for k, v in _mesh_counters().items()}
+
+
+def _host_join_hits(det: BatchDetector, qs) -> list:
+    prep = det._prepare(qs)
+    if prep is None or prep.n_pairs == 0:
+        return []
+    return det._assemble(prep, det._host_bits(prep))
+
+
+def test_dp4_merged_dispatch_matches_single_device_and_host_join(
+        table, _clean_guard):
+    """The four-chip deployment's layout, dp4 × db1 (the table whole on
+    every chip): detectd's merged dispatches over the mesh equal the
+    single-device BatchDetector and the host join, hit for hit, and
+    every one is served by the chips."""
+    from trivy_tpu.detect.sched import DispatchScheduler
+    requests = _rand_requests(61, 16)
+    single = BatchDetector(table)
+    try:
+        expected = [single.detect_many(b) for b in requests]
+        host = [[_host_join_hits(single, qs) for qs in b]
+                for b in requests]
+    finally:
+        single.close()
+    assert host == expected
+    assert sum(len(h) for b in expected for h in b) > 0
+
+    det = MeshDetector(table, make_mesh(4, db_shards=1))
+    assert dict(det.mesh.shape) == {"dp": 4, "db": 1}
+    sched = DispatchScheduler(det, SchedOptions(coalesce_wait_ms=3.0))
+    results: list = [None] * len(requests)
+    errors: list = []
+    before = _mesh_counters()
+
+    def worker(ids):
+        try:
+            for i in ids:
+                results[i] = sched.detect_many(requests[i])
+        except BaseException as e:  # noqa: BLE001
+            errors.append(e)
+
+    ts = [threading.Thread(target=worker,
+                           args=(range(k, len(requests), 4),))
+          for k in range(4)]
+    try:
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=120)
+            assert not t.is_alive()
+    finally:
+        sched.close()
+        det.close()
+    assert not errors
+    assert results == expected
+    got = _since(before)
+    assert got["device"] > 0 and got["host"] == 0
+
+
+def test_mesh_spans_nest_under_the_dispatch(table, _clean_guard):
+    """The probe, the partition, the sharded join with its gather and
+    the scatter are each timed inside `detect.dispatch`, on detectd's
+    dispatcher thread."""
+    from trivy_tpu.detect.sched import DispatchScheduler
+    from trivy_tpu.obs import COLLECTOR
+    mesh = make_mesh(4, db_shards=1)
+    guard = MeshGuard([int(d.id) for d in mesh.devices.flat],
+                      _fast_opts())
+    det = MeshDetector(table, mesh, guard=guard)
+    sched = DispatchScheduler(det, SchedOptions(coalesce_wait_ms=1.0))
+    COLLECTOR.enable()
+    try:
+        assert sched.detect_many([_queries()])[0]
+    finally:
+        COLLECTOR.disable()
+        sched.close()
+        det.close()
+        guard.close()
+    spans = COLLECTOR.drain()
+    by_id = {s.span_id: s for s in spans}
+    mesh_spans = [s for s in spans if s.name.startswith("detect.mesh.")]
+    assert {s.name for s in mesh_spans} == MESH_SPANS
+    for s in mesh_spans:
+        p = by_id.get(s.parent_id)
+        while p is not None and p.name != "detect.dispatch":
+            p = by_id.get(p.parent_id)
+        assert p is not None, f"{s.name} is not under detect.dispatch"
+        assert p.thread_id == s.thread_id
+
+
+@pytest.mark.parametrize("fault", [False, True],
+                         ids=["healthy", "faulted_domain"])
+def test_mesh_dispatches_count_what_served_them(table, _clean_guard,
+                                                fault):
+    """A healthy mesh counts one device dispatch; an armed
+    `detect.mesh:<id>` serves the dispatch from the host join, counted
+    as such."""
+    mesh = make_mesh(4, db_shards=1)
+    guard = MeshGuard([int(d.id) for d in mesh.devices.flat],
+                      _fast_opts(fail_threshold=2,
+                                 reset_timeout_ms=60000.0))
+    det = MeshDetector(table, mesh, guard=guard)
+    single = BatchDetector(table)
+    try:
+        qs = _queries()
+        want = single.detect(qs)
+        if fault:
+            FAILPOINTS.set(mesh_site(det.device_ids[1]), "error")
+        before = _mesh_counters()
+        assert det.detect(qs) == want
+        got = _since(before)
+    finally:
+        guard.close()
+        det.close()
+        single.close()
+    assert got == ({"device": 0, "host": 1} if fault
+                   else {"device": 1, "host": 0})
+
+
 class TestMeshguardDomains:
     @pytest.fixture(autouse=True)
     def _clean(self, _clean_guard):

@@ -119,3 +119,30 @@ def test_sharded_csr_join_compiles_on_dp2_db2(topo):
     # the table stays where it was placed: no collective moves it
     text = compiled.as_text()
     assert "all-gather" not in text and "all-to-all" not in text
+
+
+def test_sharded_csr_join_compact_compiles_on_dp4_db1(topo):
+    """The four-chip server's layout (`--mesh-devices 4 --mesh-db-shards
+    1`): the whole table on every chip, one pair cell per chip."""
+    from trivy_tpu.parallel.mesh import (_sharded_csr_join_compact,
+                                         mesh_from_devices)
+    mesh = mesh_from_devices(topo.devices, db_shards=1)
+    assert dict(mesh.shape) == {"dp": 4, "db": 1}
+    on_db = NamedSharding(mesh, P("db"))
+    cells = NamedSharding(mesh, P("dp", "db"))
+    rep = NamedSharding(mesh, P())
+    q_loc, t_loc = Q_PAD // 4, MID_T_PAD // 4
+    compiled = _sharded_csr_join_compact.lower(
+        mesh,
+        _sds((1, TABLE_ROWS, TOKENS), jnp.int32, on_db),
+        _sds((1, TABLE_ROWS, TOKENS), jnp.int32, on_db),
+        _sds((1, TABLE_ROWS), jnp.int32, on_db),
+        _sds((U_PAD, TOKENS), jnp.int32, rep),
+        _sds((4, 1, q_loc), jnp.int32, cells),
+        _sds((4, 1, q_loc), jnp.int32, cells),
+        _sds((4, 1, q_loc), jnp.int32, cells),
+        _sds((4, 1), jnp.int32, cells),
+        t_loc, t_loc // 16).compile()
+    text = compiled.as_text()
+    assert "all-gather" not in text and "all-to-all" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30

@@ -332,6 +332,13 @@ METRICS.declare(
     "single-chip shrinks into ONE debounced dp×db re-factorization "
     "over the survivors.")
 METRICS.declare(
+    "trivy_tpu_mesh_dispatches_total", "counter",
+    "Mesh detect dispatches by what served them: path=\"device\" the "
+    "sharded join on the chips, path=\"host\" any host-join fallback "
+    "(a lost or faulted domain, the open backend breaker, a failed "
+    "launch, the zero-survivor mode); one per dispatch that either "
+    "served.")
+METRICS.declare(
     "trivy_tpu_fleet_replica_state", "gauge",
     "graftfleet per-replica fault domain: 0 closed, 1 open, 2 "
     "half-open (one series per replica URL).")

@@ -41,6 +41,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..db.table import AdvisoryTable
 from ..detect import feed as _feed
+from ..metrics import METRICS
+from ..obs import span
 from ..ops import join as J
 from ..ops import next_pow2 as _next_pow2
 from ..resilience.hostjoin import CompactBits
@@ -385,7 +387,7 @@ class MeshDetector:
         the full merged pair space. Returns (bits, per-prep offsets,
         t_pad) in FULL merged space — bits are host-side already
         (sharded_csr_join fetches synchronously)."""
-        from ..obs import note_dispatch, span
+        from ..obs import note_dispatch
         inner = self._inner
         merged, plan, launch = inner._plan_and_launch_args(preps, plan)
         _qs, _qc, _qv, offsets, total, t_pad, u_pad = merged
@@ -445,6 +447,7 @@ class MeshDetector:
             # own, hence fallback_counts_slo)
             if not fallback_counts_slo:
                 SLO.observe_join(False)
+            METRICS.inc("trivy_tpu_mesh_dispatches_total", path="host")
             return raw_fallback()
 
         if self.mesh is None or \
@@ -459,7 +462,9 @@ class MeshDetector:
         # forever (the PR 4 dead-backend lesson)
         if self.guard is not None:
             try:
-                self.guard.check(self.device_ids)
+                with span("detect.mesh.probe",
+                          devices=len(self.device_ids)):
+                    self.guard.check(self.device_ids)
             except DeviceError:
                 _get_logger("mesh").warning(
                     "mesh domain probe failed; host-fallback join",
@@ -470,19 +475,19 @@ class MeshDetector:
         # over the mesh; the streamed path clips it to the hash-range
         # slices it touches and partitions per slice
         part = plans = parts = None
-        if self._stream_bounds is not None:
-            from .stream import clip_descriptors
-            plans = clip_descriptors(self._stream_bounds, q_start,
-                                     q_count, q_ver)
-            if not plans:
-                out = np.zeros(t_pad, np.int8)
-                return out
-            parts = [partition_queries(self.st, p.q_start, p.q_count,
-                                       p.q_ver, self.dp)
-                     for p in plans]
-        else:
-            part = partition_queries(self.st, q_start, q_count, q_ver,
-                                     self.dp)
+        with span("detect.mesh.partition", dp=self.dp):
+            if self._stream_bounds is not None:
+                from .stream import clip_descriptors
+                plans = clip_descriptors(self._stream_bounds, q_start,
+                                         q_count, q_ver)
+                if not plans:
+                    return np.zeros(t_pad, np.int8)
+                parts = [partition_queries(self.st, p.q_start, p.q_count,
+                                           p.q_ver, self.dp)
+                         for p in plans]
+            else:
+                part = partition_queries(self.st, q_start, q_count,
+                                         q_ver, self.dp)
         # allow_device() LAST, immediately before the watch: when it
         # admits the half-open probe, the watch's exit is guaranteed
         # to record the probe's outcome (success, error, or timeout)
@@ -530,14 +535,16 @@ class MeshDetector:
                     # shape)
                     h_loc = inner._hit_capacity(part.t_loc)
 
+                    # the scatter builds the result at the padded
+                    # dispatch length downstream slicing expects
                     def _join():
                         if h_loc:
                             return sharded_csr_join_compact(
                                 self.mesh, self._st_dev, ver_dev,
-                                part, total, h_loc)
+                                part, t_pad, h_loc)
                         return sharded_csr_join(
                             self.mesh, self._st_dev, ver_dev, part,
-                            total), 0
+                            t_pad), 0
                     # shared synchronous-site accounting (stream.py):
                     # compile bookkeeping + the ledger dispatch row
                     from .stream import ledgered_sync_join
@@ -559,6 +566,7 @@ class MeshDetector:
             if self.guard is not None:
                 self.guard.request_attribution()
             return host_fallback()
+        METRICS.inc("trivy_tpu_mesh_dispatches_total", path="device")
         if plans is not None:
             # streamed: bits is already the merged global result and
             # per-slice transfers were noted in the walk; adapt the
@@ -576,14 +584,10 @@ class MeshDetector:
             _cost.ledgered_transfer("compact",
                                     float(bits.pair_idx.nbytes
                                           + bits.bits.nbytes))
-            # hits already in global pair order; extend the logical
-            # dense length to the padded dispatch size downstream
-            # slicing expects
-            return CompactBits(bits.pair_idx, bits.bits, t_pad)
-        _cost.ledgered_transfer("dense", float(bits.nbytes))
-        out = np.zeros(t_pad, np.int8)
-        out[:total] = bits
-        return out
+        else:
+            # one int8 per real pair, as the dense result carries them
+            _cost.ledgered_transfer("dense", float(total))
+        return bits
 
     def _walk_mesh_slices(self, plans, parts, ver_dev, total: int,
                           t_pad: int, site: str):
@@ -642,7 +646,8 @@ class MeshDetector:
         # ONE traffic observation per logical mesh dispatch; the
         # ledger above carries the per-slice collective launches
         inner._account_traffic(total, t_total_sum)
-        return merge_slice_bits(results, t_pad), hit_notes
+        with span("detect.mesh.scatter", slices=len(results)):
+            return merge_slice_bits(results, t_pad), hit_notes
 
     def _bits(self, prep) -> np.ndarray:
         inner = self._inner
@@ -658,7 +663,6 @@ class MeshDetector:
         synchronous (its result gather IS the fetch), so there is no
         async window to pipeline — the server gets its overlap from
         detectd coalescing on top of this surface instead."""
-        from ..metrics import METRICS
         inner = self._inner
         out = []
         n_queries = n_pairs = n_hits = 0
@@ -825,23 +829,59 @@ def _cell_descriptors(mesh: Mesh, part: QueryPartition) -> list:
             (part.q_start, part.q_count, part.q_ver, part.total)]
 
 
-def sharded_csr_join(mesh: Mesh, st, ver_tok, part: QueryPartition,
-                     n_pairs: int) -> np.ndarray:
-    """CSR variant of sharded_pair_join: ships [DP, S, Q_loc]
-    descriptors, devices expand pairs locally. → int8[n_pairs] bits in
-    the caller's original pair order."""
-    from ..metrics import METRICS
-    bits = jax.device_get(_sharded_csr_join(
-        mesh, jnp.asarray(st.lo_tok), jnp.asarray(st.hi_tok),
-        jnp.asarray(st.flags), jnp.asarray(ver_tok),
-        *_cell_descriptors(mesh, part),
-        part.t_loc))
-    METRICS.inc("trivy_tpu_detect_transfer_bytes_total",
-                float(bits.nbytes), path="dense")
+def _scatter_dense(part: QueryPartition, bits: np.ndarray,
+                   n_pairs: int) -> np.ndarray:
+    """Dense cell bits int8[DP, S, T_loc] → int8[n_pairs] in global
+    pair order (part.perm); pairs past the real ones stay 0."""
     out = np.zeros(n_pairs, np.int8)
     v = part.valid
     out[part.perm[v]] = bits[v]
     return out
+
+
+def _scatter_hits(part: QueryPartition, hit_idx: np.ndarray,
+                  hit_bits: np.ndarray, n_hits: np.ndarray,
+                  n_pairs: int) -> CompactBits:
+    """Each cell's first n_hits (cell-local position, bits) → one
+    CompactBits over n_pairs in ascending global pair order."""
+    gidx: list = []
+    gbits: list = []
+    dp, s_count = n_hits.shape
+    for d in range(dp):
+        for s in range(s_count):
+            k = int(n_hits[d, s])
+            if not k:
+                continue
+            gidx.append(part.perm[d, s][hit_idx[d, s, :k]])
+            gbits.append(hit_bits[d, s, :k])
+    if not gidx:
+        return CompactBits(np.zeros(0, np.int32), np.zeros(0, np.int8),
+                           n_pairs)
+    gi = np.concatenate(gidx)
+    gb = np.concatenate(gbits)
+    # strided perm interleaves the cells' global indices — restore the
+    # caller's ascending pair order (host-side; the device epilogue
+    # stays sort-free)
+    order = np.argsort(gi, kind="stable")
+    return CompactBits(gi[order].astype(np.int32), gb[order], n_pairs)
+
+
+def sharded_csr_join(mesh: Mesh, st, ver_tok, part: QueryPartition,
+                     n_pairs: int) -> np.ndarray:
+    """CSR variant of sharded_pair_join: ships [DP, S, Q_loc]
+    descriptors, devices expand pairs locally. → int8[n_pairs] bits in
+    the caller's original pair order (n_pairs ≥ the real pairs)."""
+    with span("detect.mesh.join", cells=int(part.total.size),
+              t_loc=part.t_loc):
+        bits = jax.device_get(_sharded_csr_join(
+            mesh, jnp.asarray(st.lo_tok), jnp.asarray(st.hi_tok),
+            jnp.asarray(st.flags), jnp.asarray(ver_tok),
+            *_cell_descriptors(mesh, part),
+            part.t_loc))
+    METRICS.inc("trivy_tpu_detect_transfer_bytes_total",
+                float(bits.nbytes), path="dense")
+    with span("detect.mesh.scatter"):
+        return _scatter_dense(part, bits, n_pairs)
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "t_pad", "h_cap"))
@@ -886,43 +926,26 @@ def sharded_csr_join_compact(mesh: Mesh, st, ver_tok,
     construction either way.
 
     → (CompactBits | dense int8[n_pairs], max per-cell hit count)."""
-    from ..metrics import METRICS
-    out = _sharded_csr_join_compact(
-        mesh, jnp.asarray(st.lo_tok), jnp.asarray(st.hi_tok),
-        jnp.asarray(st.flags), jnp.asarray(ver_tok),
-        *_cell_descriptors(mesh, part),
-        part.t_loc, h_cap)
-    hit_idx, hit_bits, n_hits = jax.device_get(out[:3])
+    dense = None
+    with span("detect.mesh.join", cells=int(part.total.size),
+              t_loc=part.t_loc, h_cap=h_cap):
+        out = _sharded_csr_join_compact(
+            mesh, jnp.asarray(st.lo_tok), jnp.asarray(st.hi_tok),
+            jnp.asarray(st.flags), jnp.asarray(ver_tok),
+            *_cell_descriptors(mesh, part),
+            part.t_loc, h_cap)
+        hit_idx, hit_bits, n_hits = jax.device_get(out[:3])
+        max_hits = int(n_hits.max(initial=0))
+        if max_hits > h_cap:
+            dense = jax.device_get(out[3])
     METRICS.inc("trivy_tpu_detect_transfer_bytes_total",
                 float(hit_idx.nbytes + hit_bits.nbytes + n_hits.nbytes),
                 path="compact")
-    max_hits = int(n_hits.max(initial=0))
-    if max_hits > h_cap:
-        bits = jax.device_get(out[3])
+    if dense is not None:
         METRICS.inc("trivy_tpu_detect_transfer_bytes_total",
-                    float(bits.nbytes), path="dense")
-        dense = np.zeros(n_pairs, np.int8)
-        v = part.valid
-        dense[part.perm[v]] = bits[v]
-        return dense, max_hits
-    gidx: list = []
-    gbits: list = []
-    dp, s_count = n_hits.shape
-    for d in range(dp):
-        for s in range(s_count):
-            k = int(n_hits[d, s])
-            if not k:
-                continue
-            gidx.append(part.perm[d, s][hit_idx[d, s, :k]])
-            gbits.append(hit_bits[d, s, :k])
-    if not gidx:
-        return CompactBits(np.zeros(0, np.int32),
-                           np.zeros(0, np.int8), n_pairs), max_hits
-    gi = np.concatenate(gidx)
-    gb = np.concatenate(gbits)
-    # strided perm interleaves the cells' global indices — restore the
-    # caller's ascending pair order (host-side; the device epilogue
-    # stays sort-free)
-    order = np.argsort(gi, kind="stable")
-    return CompactBits(gi[order].astype(np.int32), gb[order],
-                       n_pairs), max_hits
+                    float(dense.nbytes), path="dense")
+    with span("detect.mesh.scatter"):
+        if dense is not None:
+            return _scatter_dense(part, dense, n_pairs), max_hits
+        return _scatter_hits(part, hit_idx, hit_bits, n_hits,
+                             n_pairs), max_hits
