@@ -582,6 +582,13 @@ METRICS.declare(
     "(path=\"pallas\"/\"jnp\"/\"host\") — the MB/s numerator for each "
     "lane of the secrets engine.")
 METRICS.declare(
+    "trivy_tpu_secret_confirm_total", "counter",
+    "Host regex confirmations, one per (file, rule) candidate, by "
+    "path=\"anchored\" (the rule's literals found first and the regex "
+    "tried only at the starts that can reach one) or \"full\" (the "
+    "regex over the whole file: no anchor could be derived from the "
+    "rule's pattern) — how often the anchored path engages.")
+METRICS.declare(
     "trivy_tpu_secret_candidate_precision", "histogram",
     "Per scan batch: keyword-gated (file, rule) candidates that the "
     "rule regex then confirmed with a finding, divided by candidates "

@@ -50,6 +50,7 @@ from ..metrics import METRICS
 from ..obs import cost as _cost
 from ..obs import span
 from ..ops import ac
+from .anchor import finditer
 from .rules import BUILTIN_RULES, GLOBAL_ALLOW_RULES, Rule
 
 CHUNK_LEN = 16384
@@ -391,10 +392,12 @@ class SecretScanner:
 
     def scan_file(self, path: str, content: bytes,
                   candidate_rules: Optional[set] = None) -> T.Secret:
+        # no candidate rule, nothing to confirm: the file is not decoded
+        if candidate_rules is not None and not candidate_rules:
+            return T.Secret(file_path=path)
         if any(a.path and a.path.search(path) for a in self.global_allow):
             return T.Secret(file_path=path)
         text = content.decode("utf-8", errors="surrogateescape")
-        censored = None
         matched = []
         global_exb = _blocks(text, self.global_exclude) \
             if self.global_exclude else []
@@ -413,30 +416,31 @@ class SecretScanner:
             if not locs:
                 continue
             exb = _blocks(text, rule.exclude_regexes) + global_exb
-            for start, end in locs:
-                if _in_blocks(start, end, exb):
-                    continue
-                matched.append((rule, start, end))
-                if censored is None:
-                    censored = list(text)
-                for i in range(start, end):
-                    censored[i] = "*"
+            matched.extend((rule, start, end) for start, end in locs
+                           if not _in_blocks(start, end, exb))
         if not matched:
             return T.Secret(file_path=path)
-        censored_text = "".join(censored)
+        censored_text = _censor(text, [(s, e) for _, s, e in matched])
         findings = [self._to_finding(rule, s, e, censored_text)
                     for rule, s, e in matched]
         findings.sort(key=lambda f: (f.rule_id, f.match))
         return T.Secret(file_path=path, findings=findings)
 
     def _find_locations(self, rule: Rule, text: str):
+        # one confirmation per (file, rule); an anchored rule tries only
+        # the starts from which a match can reach one of its literals
+        anchor = rule.anchor
+        METRICS.inc("trivy_tpu_secret_confirm_total",
+                    path="full" if anchor is None else "anchored")
+        matches = rule.regex.finditer(text) if anchor is None \
+            else finditer(rule.regex, anchor, text)
         locs = []
         if rule.secret_group:
             # a Go regex may bind the group name more than once
             # (renamed name__N at compile); each occurrence is a finding
             groups = (rule.secret_group,) + tuple(
                 getattr(rule, "secret_aliases", ()))
-            for m in rule.regex.finditer(text):
+            for m in matches:
                 if self._allowed(rule, m.group(0)):
                     continue
                 for g in groups:
@@ -447,7 +451,7 @@ class SecretScanner:
                     if s >= 0:
                         locs.append((s, e))
         else:
-            for m in rule.regex.finditer(text):
+            for m in matches:
                 if self._allowed(rule, m.group(0)):
                     continue
                 locs.append(m.span())
@@ -575,6 +579,19 @@ def _blocks(text: str, regexes) -> list[tuple[int, int]]:
 
 def _in_blocks(start: int, end: int, blocks) -> bool:
     return any(bs <= start and end <= be for bs, be in blocks)
+
+
+def _censor(text: str, spans) -> str:
+    """`text` with every character inside a span replaced by "*"."""
+    out, at = [], 0
+    for start, end in sorted(spans):
+        if end <= at:
+            continue
+        start = max(start, at)
+        out += (text[at:start], "*" * (end - start))
+        at = end
+    out.append(text[at:])
+    return "".join(out)
 
 
 _RADIUS = 2  # context lines above/below (scanner.go secretHighlightRadius)

@@ -10,9 +10,12 @@ paths etc.)."""
 
 from __future__ import annotations
 
+import functools
 import re
 from dataclasses import dataclass, field
 from typing import Optional
+
+from .anchor import Anchor, derive
 
 # shared grammar fragments
 QUOTE = r"""["']?"""
@@ -45,6 +48,12 @@ class Rule:
     path: Optional[re.Pattern] = None
     allow_rules: list = field(default_factory=list)
     exclude_regexes: list = field(default_factory=list)
+
+    @functools.cached_property
+    def anchor(self) -> Optional[Anchor]:
+        """Where a match can start (secret/anchor.py), derived from the
+        compiled pattern on first use; None keeps the whole-text scan."""
+        return derive(self.regex)
 
     def match_path(self, path: str) -> bool:
         return self.path is None or bool(self.path.search(path))
