@@ -60,7 +60,7 @@ def check(ok: bool, what: str) -> None:
 
 def require_tpu():
     """→ jax.devices() when JAX's default device is a TPU; otherwise
-    exits non-zero saying why. bench.py makes the same check."""
+    exits non-zero saying why."""
     import jax
     devices = jax.devices()
     if devices[0].platform != "tpu":

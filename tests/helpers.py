@@ -21,7 +21,7 @@ class FakeRedis:
     The reference tests use testcontainers; this fake speaks enough
     protocol for RedisCache (integration/client_server_test.go
     setupRedis) and doubles as the shared fleet backend in
-    tests/test_fleet.py and bench.py's server_fleet scenario."""
+    tests/test_fleet.py."""
 
     def __init__(self, password=""):
         self.data = {}

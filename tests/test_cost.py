@@ -122,12 +122,12 @@ class TestLedgerAndApportionment:
         assert ms1 - ms0 == pytest.approx(6.0, abs=1e-6)
 
     def test_ledgered_transfer_conserved_paths_only(self):
-        """shard_upload bytes stay out of the cost side — they are
-        host→device streaming, ledgered separately."""
+        """query_upload bytes stay out of the cost side — they are
+        host→device, ledgered separately."""
         def body():
             with cost.request_ledger("acme") as led:
                 cost.ledgered_transfer("compact", 1000)
-                cost.ledgered_transfer("shard_upload", 500)
+                cost.ledgered_transfer("query_upload", 500)
             return led
 
         led = _in_ctx(body)

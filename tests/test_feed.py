@@ -2,12 +2,11 @@
 scatter-back index map must be bit-identical to the undeduped path by
 construction — property-tested over random duplicate densities (all
 unique through all duplicate) for dense int8 and CompactBits results,
-then end-to-end through the real merged, streamed-slice and mesh
-dispatch paths; a c=8 duplicate-heavy hammer through detectd must stay
-hit-for-hit identical to serial; the double-buffered query upload must
-show steady-state stall ≈ 0 in the ledger, a hung upload must trip the
-breaker and degrade to the host join bit-identically, and a faulted
-slice prefetch must cost latency only."""
+then end-to-end through the real merged and mesh dispatch paths; a
+c=8 duplicate-heavy hammer through detectd must stay hit-for-hit
+identical to serial; the double-buffered query upload must show
+steady-state stall ≈ 0 in the ledger, and a hung upload must trip the
+breaker and degrade to the host join bit-identically."""
 
 import glob
 import os
@@ -25,7 +24,6 @@ from trivy_tpu.detect.sched import DispatchScheduler, SchedOptions
 from trivy_tpu.metrics import METRICS
 from trivy_tpu.obs.perf import LEDGER
 from trivy_tpu.parallel.mesh import MeshDetector, make_mesh
-from trivy_tpu.parallel.stream import StreamingDetector, StreamOptions
 from trivy_tpu.resilience import FAILPOINTS, GUARD
 from trivy_tpu.resilience.hostjoin import CompactBits
 from trivy_tpu.resilience.storm import storm_table
@@ -198,7 +196,7 @@ class TestPlanScatterBack:
 
 
 # ---------------------------------------------------------------------------
-# the real merged-dispatch paths: single chip, streamed slices, mesh
+# the real merged-dispatch paths: single chip and mesh
 
 
 class TestDetectorDedupPaths:
@@ -256,26 +254,6 @@ class TestDetectorDedupPaths:
             np.testing.assert_array_equal(got, want)
         finally:
             det.close()
-
-    def test_streamed_dedup_parity(self, big_table):
-        """Duplicate-heavy traffic through the slice walk: the plan
-        clips per slice exactly like the full descriptor set would."""
-        dev = big_table.device_nbytes()
-        sd = StreamingDetector(
-            big_table,
-            StreamOptions(device_budget_mb=dev / (4 * (1 << 20))))
-        bd = BatchDetector(big_table, dedup=False)
-        batches = [_dup_queries(70 + b, 24) for b in range(5)]
-        try:
-            assert sd.n_slices >= 2
-            expect = bd.detect_many(batches)
-            got = sd.detect_many(batches)
-            assert [_keys(h) for h in got] == \
-                [_keys(h) for h in expect]
-            assert sum(len(h) for h in expect) > 0
-        finally:
-            sd.close()
-            bd.close()
 
     @pytest.mark.parametrize("db_shards", [1, 2])
     def test_mesh_dedup_parity(self, big_table, db_shards):
@@ -445,57 +423,3 @@ class TestDetectdDedup:
             assert results == expected
             FAILPOINTS.configure("")
             GUARD.reset_for_tests()
-
-    def test_stream_prefetch_fault_is_latency_only(self, big_table):
-        """A faulted admission prefetch (stream.prefetch=error) must
-        cost only the lost overlap: results identical, no error
-        escapes, and the breaker never even counts it."""
-        dev = big_table.device_nbytes()
-        batches = [_dup_queries(400 + b, 24) for b in range(6)]
-        serial = BatchDetector(big_table, dedup=False)
-        expected = serial.detect_many(batches)
-        serial.close()
-        FAILPOINTS.set("stream.prefetch", "error")
-        sd = StreamingDetector(
-            big_table,
-            StreamOptions(device_budget_mb=dev / (4 * (1 << 20))))
-        sched = DispatchScheduler(
-            sd, SchedOptions(coalesce_wait_ms=3.0, prefetch=True))
-        out: dict = {}
-        try:
-            ts = [threading.Thread(
-                target=lambda k=k: out.__setitem__(
-                    k, sched.detect_many(batches[3 * k:3 * k + 3])))
-                for k in range(2)]
-            for t in ts:
-                t.start()
-            for t in ts:
-                t.join()
-            got = out[0] + out[1]
-        finally:
-            sched.close()
-            sd.close()
-        assert [_keys(h) for h in got] == \
-            [_keys(h) for h in expected]
-        assert GUARD.breaker.state_name() == "closed"
-
-    def test_prefetch_ranges_warms_touched_slices(self, big_table):
-        """The admission peek's entry point: prefetch_ranges on the
-        pending descriptors uploads exactly the touched, non-resident
-        slices (prefetched rows, no cold waits charged)."""
-        dev = big_table.device_nbytes()
-        sd = StreamingDetector(
-            big_table,
-            StreamOptions(device_budget_mb=dev / (4 * (1 << 20))))
-        try:
-            LEDGER.reset_for_tests()
-            prep = sd._prepare(_dup_queries(77, 24))
-            assert prep is not None and prep.n_pairs > 0
-            sd.prefetch_ranges(prep.q_start[:prep.n_queries],
-                               prep.q_count[:prep.n_queries])
-            stats = LEDGER.shard_upload_stats()["stream"]
-            assert stats["uploads"] >= 1
-            assert stats["prefetched"] == stats["uploads"]
-            assert stats["cold_waits"] == 0
-        finally:
-            sd.close()

@@ -405,10 +405,9 @@ def test_feed_no_clocks_in_device_code():
 
 
 def test_feed_upload_failpoint_in_device_code_detected():
-    """Satellite (PR 18): the detect.query_upload / stream.prefetch
-    probes are HOST call sites; one traced into a jitted core would
-    fire once at trace time — TPU108 must catch it in both new
-    homes."""
+    """The detect.query_upload probe is a HOST call site; one traced
+    into a jitted core would fire once at trace time — TPU108 must
+    catch it."""
     src = (
         "import jax\n"
         "from trivy_tpu.resilience import failpoint\n"
@@ -419,10 +418,6 @@ def test_feed_upload_failpoint_in_device_code_detected():
     )
     assert [(f.rule, f.line) for f in
             _lint("trivy_tpu/detect/feed.py", src)] \
-        == [("TPU108", 4)]
-    src2 = src.replace("detect.query_upload", "stream.prefetch")
-    assert [(f.rule, f.line) for f in
-            _lint("trivy_tpu/parallel/stream.py", src2)] \
         == [("TPU108", 4)]
 
 

@@ -1053,7 +1053,7 @@ class TestMeshguardRebuildRobustness:
             hung.set()
 
 
-# ---- host-level fault domains (graftstream PR) ------------------------
+# ---- host-level fault domains -----------------------------------------
 
 class TestHostFaultDomains:
     """meshguard host_of: a dead host (all its devices' domains

@@ -489,8 +489,8 @@ class TestPerfcheck:
         assert perfcheck.main([str(unreadable), ok]) == 2
 
     def test_bench_wrapper_is_unwrapped(self, tmp_path):
-        """BENCH_rXX.json driver artifacts ({"parsed": {...}}) diff
-        directly against bare tails."""
+        """Wrapped tails ({"parsed": {...}}) diff directly
+        against bare tails."""
         wrapped = self._write(
             tmp_path, "wrapped.json",
             {"n": 5, "rc": 0, "parsed": {"scan_throughput": 100.0}})
@@ -509,11 +509,11 @@ class TestPerfcheck:
         assert "missing" in capsys.readouterr().out
 
     def test_recorded_bench_tail_round_trips(self, tmp_path):
-        """The repo's actual recorded rounds satisfy the tail schema —
-        the gate can baseline what the driver already records."""
-        r05 = os.path.join(os.path.dirname(__file__), "..",
-                           "BENCH_r05.json")
-        flat = perfcheck.load_tail(r05)
+        """The repo's recorded golden tail satisfies the tail schema —
+        the gate can baseline what is already recorded."""
+        base = os.path.join(os.path.dirname(__file__), "golden",
+                            "bench_tail_base.json")
+        flat = perfcheck.load_tail(base)
         assert any("images_per_sec" in k for k in flat)
 
 

@@ -391,8 +391,8 @@ def _bare_sched(share=1.0):
 
 
 class TestFairQueue:
-    """graftfair DRR sweep unit gate: share cap, deficit carry, forced
-    progress, and the prefetch peek's lap order."""
+    """graftfair DRR sweep unit gate: share cap, deficit carry, and
+    forced progress."""
 
     def test_share_cap_bounds_flooding_tenant(self):
         s = _bare_sched(share=0.5)
@@ -451,16 +451,3 @@ class TestFairQueue:
         order0 = list(s._rr)
         s._fair_take_locked(1)
         assert list(s._rr) == order0[1:] + order0[:1]
-
-    def test_peek_interleaves_one_per_tenant_per_lap(self):
-        s = _bare_sched()
-        for i in range(3):
-            s._fair_put_locked(_FakeReq("a", 1))
-            s._fair_put_locked(_FakeReq("b", 1))
-        peek = s._peek_fair_locked(4)
-        assert [r.tenant for r in peek] == ["a", "b", "a", "b"]
-        # peeking never consumes state
-        assert len(s._fair["a"]) == 3 and len(s._fair["b"]) == 3
-        assert s._fair_pairs == 6
-        # k larger than pending → everything, still interleaved
-        assert len(s._peek_fair_locked(100)) == 6

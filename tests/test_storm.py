@@ -189,7 +189,7 @@ class TestAcceptance:
             raise AssertionError("no mesh device lost in 2 drills")
 
     def test_mesh_host_loss_c8(self, table):
-        """ISSUE acceptance (graftstream): a host_loss event kills
+        """Host fault domains: a host_loss event kills
         every detect.mesh domain sharing synthetic host 1 at c=8 —
         meshguard answers with EXACTLY ONE shrink rebuild
         re-factorizing dp×db over the surviving host, zero failed

@@ -357,13 +357,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "query device dispatch (the host scatter-back "
                         "keeps every request's bits identical); "
                         "--no-detect-dedup dispatches every real pair")
-    p.add_argument("--stream-prefetch", default=True,
-                   action=argparse.BooleanOptionalAction,
-                   help="graftfeed: while a dispatch computes, warm "
-                        "the advisory slices the QUEUED requests' "
-                        "bucket ranges will touch (streamed tables "
-                        "only; advisory — a failed prefetch costs one "
-                        "cold upload); --no-stream-prefetch disables")
     p.add_argument("--detect-tenant-max-share", type=float,
                    default=1.0,
                    help="graftfair: max fraction of one merged-"
@@ -411,19 +404,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "for its siblings — a dead host then costs "
                         "ONE re-factorized dp×db rebuild instead of "
                         "N serial single-chip shrinks (default 250)")
-    p.add_argument("--table-device-budget-mb", type=float, default=0.0,
-                   help="graftstream: per-device byte budget for "
-                        "resident advisory slices; a table exceeding "
-                        "it streams through a double-buffered slice "
-                        "pair with uploads overlapped against "
-                        "compute. 0 = auto from the device's memory "
-                        "limit (graftprof hbm view; CPU backends "
-                        "never auto-engage)")
-    p.add_argument("--table-stream-slices", type=int, default=0,
-                   help="graftstream: force the advisory table to "
-                        "stream through N hash-range slices "
-                        "regardless of the byte budget (0 = size "
-                        "from --table-device-budget-mb)")
     p.add_argument("--drain-grace-ms", type=float, default=10000.0,
                    help="SIGTERM/SIGINT graceful drain: stop "
                         "admitting (503 + Retry-After), let in-flight "
@@ -1204,7 +1184,6 @@ def server_options(args) -> dict:
                                     1 << 22),
         warmup=getattr(args, "detect_warmup", False),
         dedup=getattr(args, "detect_dedup", True),
-        prefetch=getattr(args, "stream_prefetch", True),
         tenant_max_share=getattr(args, "detect_tenant_max_share",
                                  1.0))
     # meshguard: shard detection over a device mesh with per-device
@@ -1220,11 +1199,7 @@ def server_options(args) -> dict:
                                  5000.0),
         hosts=getattr(args, "mesh_hosts", 0),
         host_loss_window_ms=getattr(args, "mesh_host_loss_window_ms",
-                                    250.0),
-        table_device_budget_mb=getattr(args, "table_device_budget_mb",
-                                       0.0),
-        table_stream_slices=getattr(args, "table_stream_slices", 0),
-        stream_prefetch=getattr(args, "stream_prefetch", True))
+                                    250.0))
     # graftmemo + redetectd: result memoization keyed by (blob digest,
     # db_version), with the post-swap background re-detect sweep
     from .detect.redetect import RedetectOptions

@@ -1,7 +1,6 @@
 """JAX's persistent compilation cache, shared by every entry point.
 
-`cli.main`, `chip_smoke.py` and `bench.py` call `enable()` before any
-compile. Where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself
+`cli.main` and `chip_smoke.py` call `enable()` before any compile. Where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself
 and nothing is set in code. Otherwise the cache lives at a fixed
 `.jax_cache/` in the checkout: the directory is part of what a later
 process looks up, so it is never a temp name, a pid or a time.

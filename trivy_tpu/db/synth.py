@@ -4,7 +4,7 @@
 plus one `linux`-style source package carrying 4,000 advisory rows:
 about 205k interval rows. Fixed versions skew low and the installed
 pool skews high, so an 80-package image hits ~30 CVEs, the density of
-real images. bench.py and chip_smoke.py both build their tables here.
+real images. chip_smoke.py builds its tables here.
 """
 
 from __future__ import annotations

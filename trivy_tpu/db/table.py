@@ -123,9 +123,8 @@ class AdvisoryTable:
         return self._hash_u64
 
     def nbytes_by_column(self) -> dict:
-        """Per-column byte accounting of the flattened table — the
-        graftstream slice planner's sizing input and graftprof's
-        per-component `resident_bytes` breakdown (/healthz
+        """Per-column byte accounting of the flattened table —
+        graftprof's per-component `resident_bytes` breakdown (/healthz
         `device.memory`). Keys are the column names; `hash_u64` only
         appears once the lazy lookup view has been built."""
         cols = {

@@ -1,8 +1,7 @@
-"""graftfeed — the input-path twin of graftstream and hit compaction.
+"""graftfeed — the input-path twin of hit compaction.
 
-PR 10 compacted the join's *output* (O(hits) device→host) and
-graftstream double-buffered the *table* (host→device slice uploads);
-this module closes the remaining input-path gaps:
+Hit compaction made the join's *output* O(hits) device→host; this
+module closes the input-path gaps:
 
   * **Cross-request unique-query dedup** (`plan_merged`/`expand_bits`):
     when detectd merges coalesced descriptors, each real query is fully
@@ -22,16 +21,15 @@ this module closes the remaining input-path gaps:
   * **Double-buffered query upload** (`stage_queries`/`upload_queries`):
     the padded CSR query columns used to device_put synchronously
     inside the launch window. detectd now stages the upload for
-    dispatch i+1 while dispatch i computes (the H2D mirror of
-    graftstream's overlap), supervised by its own
+    dispatch i+1 while dispatch i computes, supervised by its own
     `detect.query_upload` GUARD.watch so a wedged upload trips the
     breaker exactly like a wedged launch. Stalls are ledgered as
-    `query_upload` rows next to graftstream's `shard_upload` ones:
-    steady-state stall ≈ 0 is an asserted property, not a hope.
+    `query_upload` rows in the graftprof upload ledger: steady-state
+    stall ≈ 0 is an asserted property, not a hope.
 
-Admission-aware slice *prefetch* (the third graftfeed piece) lives
-with the slice machinery in parallel/stream.py (`touched_slices`,
-`prefetch_ranges`); detectd's dispatcher drives it between rounds.
+The advisory table itself is resident: whole on one chip, or
+db-sharded over the mesh (parallel/mesh.py) — so a dispatch moves
+only its query columns and its results.
 """
 
 from __future__ import annotations
@@ -257,7 +255,7 @@ def upload_queries(q_start: np.ndarray, q_count: np.ndarray,
                    q_ver: np.ndarray, prefetched: bool):
     """device_put the CSR query columns (async on real accelerators)
     under the `detect.query_upload` failpoint, ledgering the H2D bytes
-    as a `query_upload` transfer next to graftstream's shard uploads.
+    as a `query_upload` transfer in the graftprof upload ledger.
     `prefetched` = staged ahead of need (detectd's double buffer);
     False = the upload ran inside the launch window (the cold path)."""
     import jax

@@ -2,8 +2,8 @@
 
 Every per-request path pays the same tax: a 16-client registry sweep
 runs 16 concurrent `detect_many` calls, each dispatching its own
-pow2-padded join (BENCH_r05: 57.3 images/s through the server vs 82.7
-local on the same backend — the gap is almost entirely dispatch
+pow2-padded join (before detectd, a server measured 57.3 images/s vs
+82.7 local on the same backend — the gap is almost entirely dispatch
 overhead and padding waste multiplied by request count). detectd
 closes it the way inference servers do, with continuous batching:
 
@@ -51,23 +51,18 @@ from collections import deque
 from concurrent.futures import Future, InvalidStateError
 from dataclasses import dataclass
 
-import numpy as np
-
-from ..log import get as _get_logger
 from ..metrics import METRICS
 from ..obs import cost as _cost
 from ..obs import current_trace_id, span
 from . import feed as _feed
 from .engine import BatchDetector, Hit, PkgQuery, slice_bits
 
-_log = _get_logger("sched")
-
 
 @dataclass
 class SchedOptions:
     """detectd knobs (server flags --detect-coalesce-wait-ms,
     --detect-max-inflight-pairs, --detect-warmup, --detect-dedup,
-    --stream-prefetch, --detect-tenant-max-share)."""
+    --detect-tenant-max-share)."""
     coalesce_wait_ms: float = 2.0     # max wait gathering co-dispatchers
     max_pairs_in_flight: int = 1 << 22  # padded-pair in-flight bound
     warmup: bool = False              # pre-compile the bucket ladder
@@ -75,8 +70,6 @@ class SchedOptions:
     enabled: bool = True              # False → per-request dispatch
     dedup: bool = True                # graftfeed: collapse duplicate
     #                                   query triples across the merge
-    prefetch: bool = True             # graftfeed: warm the next
-    #                                   dispatch's advisory slices
     tenant_max_share: float = 1.0     # graftfair: max fraction of a
     #                                   merged round's pair budget one
     #                                   tenant may fill while other
@@ -171,9 +164,7 @@ class DispatchScheduler:
         # here (under self._lock) BEFORE putting its wake token on
         # self._queue; the dispatcher pops rounds via _fair_take.
         # Tenant labels are aggregator-clamped, so the dicts stay
-        # bounded at top-K + reserved. graftfeed's prefetch peeks the
-        # same structure in drain order (not insertion order), so it
-        # warms the NEXT round's slices even under a tenant flood
+        # bounded at top-K + reserved.
         self._fair: dict[str, deque] = {}
         self._rr: deque[str] = deque()       # tenant rotation order
         self._deficit: dict[str, float] = {}  # DRR deficit counters
@@ -350,27 +341,6 @@ class DispatchScheduler:
             self._rr.rotate(-1)
         return taken
 
-    def _peek_fair_locked(self, k: int) -> list[_Request]:
-        """First ≤k requests in the fair sweep's drain order (one per
-        tenant per lap, round-robin) WITHOUT popping — the prefetch
-        peek. Approximates _fair_take_locked's interleave without
-        consuming deficits."""
-        out: list[_Request] = []
-        lap = 0
-        while len(out) < k:
-            advanced = False
-            for label in self._rr:
-                dq = self._fair.get(label)
-                if dq is not None and lap < len(dq):
-                    out.append(dq[lap])
-                    advanced = True
-                    if len(out) >= k:
-                        break
-            if not advanced:
-                break
-            lap += 1
-        return out
-
     def _drain_tokens(self) -> bool:
         """Consume every wake token already queued (their requests are
         in the fair structure). → True when the close() sentinel was
@@ -418,8 +388,6 @@ class DispatchScheduler:
                 # survive any one round; the affected requests fail
                 for req in pending:
                     req.fail(e)
-            if opts.prefetch:
-                self._prefetch_pending()
 
     def _gather(self, stopping: bool) -> tuple[bool, list[_Request]]:
         """Sweep everything already queued (free coalescing), then
@@ -448,8 +416,8 @@ class DispatchScheduler:
             if tok is None:
                 stopping = True
         # graftfair: pop the round in deficit-round-robin order — a
-        # flooding tenant's surplus stays queued (and visible to the
-        # prefetch peek) instead of monopolizing the window
+        # flooding tenant's surplus stays queued instead of
+        # monopolizing the window
         with self._lock:
             pending = self._fair_take_locked(opts.max_pairs_in_flight)
         return stopping, pending
@@ -468,39 +436,6 @@ class DispatchScheduler:
             METRICS.observe("trivy_tpu_tenant_qos_dispatch_share",
                             pairs / total, tenant=label)
 
-    def _prefetch_pending(self) -> None:
-        """graftfeed slice prefetch: peek the requests still queued
-        behind the round that just dispatched and ask a streaming
-        detector to warm the advisory slices their bucket ranges will
-        touch. The peek follows the FAIR sweep's drain order (round-
-        robin across tenants), so under a tenant flood it warms the
-        next dispatch's slices, not the flood's backlog. Advisory only
-        — any failure costs at most a cold upload on the next
-        dispatch, never correctness — so every error is swallowed here
-        (the failpoint drill in tests/test_feed.py leans on that)."""
-        pf = getattr(self.detector, "prefetch_ranges", None)
-        if pf is None:
-            return
-        with self._lock:
-            reqs = self._peek_fair_locked(8)
-        if not reqs:
-            return
-        starts: list[np.ndarray] = []
-        counts: list[np.ndarray] = []
-        for r in reqs:
-            for _slot, p in r.slots:
-                k = p.n_queries
-                if k:
-                    starts.append(p.q_start[:k])
-                    counts.append(p.q_count[:k])
-        if not starts:
-            return
-        try:
-            pf(np.concatenate(starts), np.concatenate(counts))
-        except BaseException:  # noqa: BLE001 — latency-only path
-            _log.warning("pending-slice prefetch failed; the next "
-                         "dispatch uploads cold", exc_info=True)
-
     def _dispatch_round(self, pending: list[_Request]) -> None:
         """Chunk the gathered slots under the pair budget and issue one
         merged dispatch per chunk."""
@@ -516,8 +451,7 @@ class DispatchScheduler:
             # graftfeed: merge + dedup-plan + stage the query upload
             # BEFORE parking on backpressure — while a prior dispatch
             # still owns the device, its compute time hides this
-            # chunk's H2D transfer (the input-path mirror of
-            # graftstream's shard double-buffering). Detectors without
+            # chunk's H2D transfer. Detectors without
             # the graftfeed surface (test fakes, older shims) take the
             # bare dispatch_merged path unchanged
             dedup_on = self.opts.dedup and getattr(det, "dedup", False)

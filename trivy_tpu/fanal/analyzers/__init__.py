@@ -188,9 +188,8 @@ class AnalyzerGroup:
         # graftwatch attribution: one span per analyzer DISPATCH (an
         # analyzer that actually ran on this file), not per candidate
         # — required() gates keep the span count proportional to real
-        # work, and bench.py's archive breakdown aggregates these into
-        # the analyzer_ms phase the fanal-pipeline rebuild (ROADMAP 1)
-        # will be judged against
+        # work, and a per-phase breakdown aggregates these into an
+        # analyzer_ms phase
         from ...obs import span
         for a in self.analyzers:
             if self._wants(a, path, len(content)):

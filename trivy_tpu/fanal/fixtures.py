@@ -2,7 +2,7 @@
 
 One implementation of the tar/gzip/docker-save layout shared by every
 in-repo producer of synthetic images — graftstorm's ingest-drill
-artifacts and bench.py's archive fixtures — so a change to the layout
+artifacts and tests/helpers.py make_image — so a change to the layout
 (layer path naming, config history shape) cannot leave one builder
 emitting archives the fanal artifact code no longer accepts. Zeroed
 tar/gzip mtimes keep the bytes reproducible."""

@@ -45,9 +45,8 @@ CONFIG_PATHS = {
     "detect_coalesce_wait_ms": "detect.coalesce-wait-ms",
     "detect_max_inflight_pairs": "detect.max-inflight-pairs",
     "detect_warmup": "detect.warmup",
-    # graftfeed (input path): cross-request dedup + slice prefetch
+    # graftfeed (input path): cross-request dedup
     "detect_dedup": "detect.dedup",
-    "stream_prefetch": "mesh.stream-prefetch",
     # graftguard (resilience.*): watchdog, breaker, admission,
     # failpoints
     "detect_dispatch_timeout_ms": "resilience.dispatch-timeout-ms",
@@ -65,10 +64,6 @@ CONFIG_PATHS = {
     "mesh_probe_timeout_ms": "mesh.probe-timeout-ms",
     "mesh_hosts": "mesh.hosts",
     "mesh_host_loss_window_ms": "mesh.host-loss-window-ms",
-    # graftstream (larger-than-device advisory tables) rides the
-    # mesh.* config section — it is the mesh data dimension made real
-    "table_device_budget_mb": "mesh.table-device-budget-mb",
-    "table_stream_slices": "mesh.table-stream-slices",
     # graftfleet (fleet.* / cache.*): scan router + shared backends
     "cache_backend": "cache.backend",
     "replicas": "fleet.replicas",

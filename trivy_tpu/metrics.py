@@ -476,9 +476,8 @@ METRICS.declare(
     "graftprof dispatch ledger: accepted device launches by site "
     "(site=\"detect\" single-chip engine, \"detectd\" merged "
     "coalesced dispatches, \"mesh\" sharded mesh launches, "
-    "\"stream\" per-slice graftstream launches, \"secret\" the "
-    "shift-or secrets engine, \"redetect\" blameless redetectd sweep "
-    "replays). Warmup launches are compiles, not traffic, and are "
+    "\"secret\" the shift-or secrets engine, \"redetect\" blameless "
+    "redetectd sweep replays). Warmup launches are compiles, not traffic, and are "
     "excluded.")
 METRICS.declare(
     "trivy_tpu_device_padding_waste_ratio", "histogram",
@@ -502,9 +501,8 @@ METRICS.declare(
     "(path=\"compact\" O(hits) hit buffers, path=\"dense\" full "
     "padded vectors, path=\"overflow\" the dense re-fetch a hit-"
     "buffer overflow pays on top of its wasted compact fetch — all "
-    "device->host; path=\"shard_upload\" graftstream host->device "
-    "advisory-slice uploads; path=\"query_upload\" graftfeed "
-    "host->device CSR query-column uploads) — unlike "
+    "device->host; path=\"query_upload\" graftfeed host->device "
+    "CSR query-column uploads) — unlike "
     "trivy_tpu_detect_transfer_bytes_total this series separates the "
     "overflow re-fetch and covers every ledger site.")
 METRICS.declare(
@@ -534,20 +532,17 @@ METRICS.declare(
     "Host-resident footprint of the big scan structures "
     "(component=\"advisory_table\" columnar arrays plus its "
     "per-column \"advisory_table.<col>\" breakdown, "
-    "\"advisory_slice_resident\" the graftstream double-buffered "
-    "device slice pair, \"version_pool\" the encoded version matrix, "
+    "\"version_pool\" the encoded version matrix, "
     "\"secret_bank\" the shift-or word/mask planes) — the "
     "table-growth-toward-the-HBM-cliff early warning /healthz "
     "surfaces.")
 METRICS.declare(
     "trivy_tpu_device_upload_stall_ms", "histogram",
-    "graftstream/graftfeed: time one dispatch blocked making an "
-    "advisory slice (or, for the query_upload ledger rows, its CSR "
-    "query columns) device-resident. Double buffering prefetches the "
-    "next upload during the previous dispatch's compute, so "
-    "steady-state stalls sit in the lowest bucket; mass above it "
-    "means transfer is outrunning compute (shrink the slice count or "
-    "grow the budget).",
+    "graftfeed: time one dispatch blocked making its CSR query "
+    "columns device-resident. Double buffering stages the next "
+    "upload during the previous dispatch's compute, so steady-state "
+    "stalls sit in the lowest bucket; mass above it means transfer "
+    "is outrunning compute.",
     buckets=(0.1, 0.5, 1.0, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0,
              1000.0))
 METRICS.declare(

@@ -81,9 +81,8 @@ _SHARES: contextvars.ContextVar = contextvars.ContextVar(
     "trivy_tpu_cost_shares", default=None)
 
 # transfer paths that participate in the conservation contract:
-# device→host result bytes. shard_upload (host→device streaming) is
-# excluded — it is charged per-walk by the streaming layer and the
-# ledger already reports it separately under shard_uploads.
+# device→host result bytes. query_upload (host→device) is excluded —
+# the ledger already reports it separately under shard_uploads.
 CONSERVED_TRANSFER_PATHS = ("compact", "dense", "overflow")
 
 # numeric ledger fields a request accumulates; secret bytes use a
@@ -178,12 +177,12 @@ SYSTEM = CostLedger("system", live=True)
 # ---------------------------------------------------------------------------
 # charge entry points (the ONE shared helper set every seam calls)
 
-# bench baseline switch: bench.py measures what graftcost itself
-# costs by re-running a point with attribution OFF. Disabled mode
-# keeps every graftprof LEDGER write (perf telemetry must not change
-# under the A/B) but skips ledger install, apportionment, and settle
-# exports. Conservation is meaningless while off — only the bench
-# A/B uses this, always restoring True in a finally.
+# baseline switch: measuring what graftcost itself costs means
+# re-running a point with attribution OFF. Disabled mode keeps every
+# graftprof LEDGER write (perf telemetry must not change under the
+# A/B) but skips ledger install, apportionment, and settle exports.
+# Conservation is meaningless while off — only such an A/B uses this,
+# always restoring True in a finally.
 _ENABLED = True
 
 

@@ -174,15 +174,13 @@ class DispatchLedger:
                 row["overflows"] += 1
 
     def note_shard_upload(self, site: str, nbytes: int,
-                          prefetched: bool,
-                          path: str = "shard_upload") -> None:
-        """One host→device upload (graftstream advisory slices;
-        graftfeed query columns with site/path "query_upload").
-        `prefetched` means the double buffer shipped it AHEAD of need,
-        overlapped with the previous slice's (or dispatch's) compute;
-        a non-prefetched upload ran inside a dispatch's wait (the cold
-        path). Counts in the transfer ledger under `path` so streaming
-        and input-feed overhead show at /debug/perf next to the result
+                          prefetched: bool, path: str) -> None:
+        """One host→device upload (graftfeed query columns, site/path
+        "query_upload"). `prefetched` means the double buffer shipped
+        it AHEAD of need, overlapped with the previous dispatch's
+        compute; a non-prefetched upload ran inside a dispatch's wait
+        (the cold path). Counts in the transfer ledger under `path` so
+        input-feed overhead shows at /debug/perf next to the result
         fetches."""
         with self._lock:
             row = self._uploads.setdefault(site, _new_upload_row())
@@ -194,11 +192,11 @@ class DispatchLedger:
 
     def note_shard_wait(self, site: str, stall_ms: float,
                         cold: bool) -> None:
-        """Time one dispatch spent blocked making a slice resident.
-        Steady-state double buffering means stalls ≈ 0 after the first
-        slice of a walk — the overlap property the streaming tests
-        assert from these rows. `cold` = the upload itself ran inside
-        this wait (nothing had prefetched the slice)."""
+        """Time one dispatch spent blocked making its uploaded
+        operands resident. Steady-state double buffering means stalls
+        ≈ 0 — the overlap property the feed tests assert from these
+        rows. `cold` = the upload itself ran inside this wait (nothing
+        had staged it ahead)."""
         with self._lock:
             row = self._uploads.setdefault(site, _new_upload_row())
             row["waits"] += 1
@@ -209,9 +207,9 @@ class DispatchLedger:
         METRICS.observe("trivy_tpu_device_upload_stall_ms", stall_ms)
 
     def shard_upload_stats(self) -> dict:
-        """→ {site: upload/stall aggregates} — the graftstream
-        overlap view (/debug/perf `shard_uploads`, bench table_sweep,
-        and the tier-1 double-buffer assertion)."""
+        """→ {site: upload/stall aggregates} — the upload overlap
+        view (/debug/perf `shard_uploads` and the tier-1 double-buffer
+        assertion)."""
         with self._lock:
             return {site: dict(row)
                     for site, row in self._uploads.items()}
@@ -360,7 +358,7 @@ class DispatchLedger:
             "device_ms": {k: round(v, 3) for k, v in device_ms.items()},
             "device_ms_total": round(sum(device_ms.values()), 3),
             "budget_adaptations": adapt,
-            # graftstream: host→device slice-upload overlap aggregates
+            # host→device upload overlap aggregates
             # (uploads/prefetched/stall_ms per site)
             "shard_uploads": uploads,
         }
@@ -417,8 +415,8 @@ def table_resident_bytes(table) -> int:
 def stamp_table_resident(table) -> int:
     """Stamp one AdvisoryTable's footprint into the resident-bytes
     view: the whole-table figure PLUS the per-column breakdown
-    (`advisory_table.lo_tok`, …) the graftstream slice planner budgets
-    from — the build sites used to stamp only the total, so /healthz
+    (`advisory_table.lo_tok`, …) — the build sites used to stamp
+    only the total, so /healthz
     could not say WHICH column was marching toward the HBM cliff."""
     cols = getattr(table, "nbytes_by_column", None)
     if not callable(cols):

@@ -7,9 +7,9 @@ tail per round and comparing them is eyeball work. This gate makes the
 comparison mechanical and noise-aware, so a recorded device round
 becomes a baseline the fleet can actually hold:
 
-  * both inputs are bench tails — the single JSON object bench.py
-    prints (a BENCH_rXX.json wrapper with a ``parsed`` object is
-    unwrapped automatically). Schema problems (not an object, no
+  * both inputs are bench tails — a single JSON object of numeric
+    metrics (a wrapper with a ``parsed`` object is unwrapped
+    automatically). Schema problems (not an object, no
     numeric metrics, NaN/Inf values) exit 2 — a malformed baseline
     must fail loudly, not silently compare nothing;
   * metrics are the numeric leaves, addressed by dotted path
@@ -122,7 +122,7 @@ def load_tail(path: str) -> dict:
     except (OSError, json.JSONDecodeError) as e:
         raise SchemaError(f"{path}: unreadable: {e}") from None
     if isinstance(doc, dict) and isinstance(doc.get("parsed"), dict):
-        doc = doc["parsed"]   # BENCH_rXX.json driver wrapper
+        doc = doc["parsed"]   # wrapped tail
     if not isinstance(doc, dict):
         raise SchemaError(f"{path}: top level is not an object")
     flat = flatten(doc)

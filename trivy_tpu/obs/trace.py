@@ -41,8 +41,8 @@ Three sinks receive finished spans (graftwatch):
     incident capture; its per-span cost is one counter bump and one
     slot store;
   * the COLLECTOR, only while recording is enabled (`--trace FILE` on
-    the CLI, the server's --trace flag, or bench.py's phase
-    breakdown) — the opt-in complete-trace dump.
+    the CLI, the server's --trace flag, or a per-phase breakdown) —
+    the opt-in complete-trace dump.
 
 Export is Chrome trace-event JSON ("X" complete events, microsecond
 timestamps), loadable in Perfetto / chrome://tracing.
@@ -234,8 +234,7 @@ class Collector:
 
     def phase_totals(self) -> dict[str, dict]:
         """The span aggregate since recording began, by name →
-        {count, self_ms, self_cpu_ms} (bench.py's per-phase
-        breakdown)."""
+        {count, self_ms, self_cpu_ms} (the per-phase breakdown)."""
         with self._lock:
             base = self._base
         out: dict[str, dict] = {}

@@ -6,8 +6,5 @@ over `dp` as the sequence axis."""
 
 from .mesh import (MeshDetector, QueryPartition,  # noqa: F401
                    ShardedTable, best_db_shards, make_mesh,
-                   mesh_from_devices, partition_queries, shard_arrays,
-                   shard_table, sharded_csr_join)
-from .stream import (SliceCache, StreamOptions,  # noqa: F401
-                     StreamingDetector, clip_descriptors,
-                     merge_slice_bits, plan_slices, slice_bounds)
+                   mesh_from_devices, partition_queries, shard_table,
+                   sharded_csr_join)

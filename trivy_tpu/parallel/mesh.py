@@ -29,7 +29,7 @@ Everything runs under one jit(shard_map(...)).
 from __future__ import annotations
 
 import functools
-import threading
+import time
 from dataclasses import dataclass
 
 import numpy as np
@@ -39,12 +39,16 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import compile_cache
 from ..db.table import AdvisoryTable
 from ..detect import feed as _feed
 from ..metrics import METRICS
+from ..obs import cost as _cost
 from ..obs import span
+from ..obs.perf import LEDGER
 from ..ops import join as J
 from ..ops import next_pow2 as _next_pow2
+from ..resilience import failpoint
 from ..resilience.hostjoin import CompactBits
 
 def make_mesh(n_devices: int | None = None, db_shards: int = 1,
@@ -97,39 +101,6 @@ class ShardedTable:
     row_len: np.ndarray     # int64[S]: real (unpadded) rows per shard
 
 
-def shard_arrays(lo_tok: np.ndarray, hi_tok: np.ndarray,
-                 flags: np.ndarray, n_shards: int,
-                 pad: int | None = None) -> ShardedTable:
-    """shard_table over raw columns: the graftstream mesh path shards
-    each hash-range SLICE of the table through here with a caller-
-    pinned `pad` (uniform local row count across every slice, so the
-    whole stream compiles one sharded-join program family instead of
-    one per slice)."""
-    a = lo_tok.shape[0]
-    lens = [max(0, (a - s + n_shards - 1) // n_shards)
-            for s in range(n_shards)]
-    if pad is None:
-        pad = max(lens) if a else 1
-
-    def _piece(arr, s, fill):
-        out = np.full((pad,) + arr.shape[1:], fill, dtype=arr.dtype)
-        part = arr[s::n_shards]
-        out[:part.shape[0]] = part
-        return out
-
-    return ShardedTable(
-        lo_tok=np.stack([_piece(lo_tok, s, 1)
-                         for s in range(n_shards)]),
-        hi_tok=np.stack([_piece(hi_tok, s, 1)
-                         for s in range(n_shards)]),
-        flags=np.stack([_piece(flags, s, 0)
-                        for s in range(n_shards)]),
-        # residue ids; kept for shape compatibility and diagnostics
-        row_offset=np.arange(n_shards, dtype=np.int64),
-        row_len=np.asarray(lens, dtype=np.int64),
-    )
-
-
 def shard_table(table: AdvisoryTable, n_shards: int) -> ShardedTable:
     """Round-robin (strided) row sharding: shard s holds global rows
     r with r % S == s at local index r // S.
@@ -141,8 +112,63 @@ def shard_table(table: AdvisoryTable, n_shards: int) -> ShardedTable:
     imbalance at 100k queries against a `linux`-style mega bucket
     (95% of pair volume landing in one shard); strided sharding makes
     that workload balance by construction."""
-    return shard_arrays(table.lo_tok, table.hi_tok, table.flags,
-                        n_shards)
+    a = table.lo_tok.shape[0]
+    lens = [max(0, (a - s + n_shards - 1) // n_shards)
+            for s in range(n_shards)]
+    pad = max(lens) if a else 1
+
+    def _piece(arr, s, fill):
+        out = np.full((pad,) + arr.shape[1:], fill, dtype=arr.dtype)
+        part = arr[s::n_shards]
+        out[:part.shape[0]] = part
+        return out
+
+    return ShardedTable(
+        lo_tok=np.stack([_piece(table.lo_tok, s, 1)
+                         for s in range(n_shards)]),
+        hi_tok=np.stack([_piece(table.hi_tok, s, 1)
+                         for s in range(n_shards)]),
+        flags=np.stack([_piece(table.flags, s, 0)
+                        for s in range(n_shards)]),
+        # residue ids; kept for shape compatibility and diagnostics
+        row_offset=np.arange(n_shards, dtype=np.int64),
+        row_len=np.asarray(lens, dtype=np.int64),
+    )
+
+
+def ledgered_sync_join(inner, run, site: str, real: int, t_total: int,
+                       q_pad: int, u_rows: int, h_cap: int,
+                       **span_attrs):
+    """Per-launch accounting for the resident mesh join, a SYNCHRONOUS
+    site: compile bookkeeping (`_note_shape` → the `detect.compile`
+    failpoint, a timed `detect.compile` span, and the ledger's compile
+    row — a synchronous site's first-of-shape wall time is compile +
+    one execution, the honest upper bound on what a mid-traffic
+    compile costs a request) followed by the ledger dispatch row.
+    `run()` performs the launch + fetch and its return value passes
+    through.
+
+    graftcost rides the same seam: a synchronous site's `run()` wall
+    time IS its device ms (launch + compute + fetch in one call), so
+    one clock read feeds the shape ledger and the per-tenant
+    apportionment — the conservation contract."""
+    new_shape = inner._note_shape(t_total, q_pad, u_rows, h_cap)
+    t_run = time.perf_counter()
+    if new_shape:
+        failpoint("detect.compile")
+        with span("detect.compile", t_pad=t_total, h_cap=h_cap,
+                  **span_attrs), compile_cache.watch() as got:
+            t0 = time.perf_counter()
+            out = run()
+            compile_ms = (time.perf_counter() - t0) * 1e3
+        LEDGER.note_compile(site, t_total, h_cap, compile_ms,
+                            loaded=got.compiled == 0)
+    else:
+        out = run()
+    _cost.charge_device_ms(site, (time.perf_counter() - t_run) * 1e3,
+                           real_rows=0 if new_shape else real)
+    LEDGER.note_dispatch(site, real, t_total, h_cap)
+    return out
 
 
 def sharded_shiftor_scan(mesh: Mesh, kw_words, kw_masks,
@@ -202,7 +228,7 @@ class MeshDetector:
     def __init__(self, table: AdvisoryTable, mesh: Mesh | None,
                  db_shards: int | None = None, guard=None,
                  compact: bool = True, hit_floor: int = 128,
-                 hit_align: int = 128, stream=None):
+                 hit_align: int = 128):
         from ..detect.engine import BatchDetector
         self.mesh = mesh
         self.table = table
@@ -216,16 +242,6 @@ class MeshDetector:
         # dispatches accept a dedup plan and partition only the
         # UNIQUE query set over the mesh
         self.dedup = self._inner.dedup
-        self._stream_prefetch = bool(stream is not None
-                                     and stream.prefetch)
-        # graftstream (stream=StreamOptions): when the PER-DEVICE
-        # share of the sharded table (whole device footprint ÷ db
-        # width) exceeds the budget, the table streams through a
-        # double-buffered resident slice pair instead of living on
-        # device whole — None / within-budget keeps the resident path
-        # byte-for-byte unchanged
-        self._stream_bounds = None
-        self._slice_cache = None
         if mesh is None:
             # host-only degraded mode (meshguard: survivors below
             # --mesh-min-devices): no shard, no upload, no device ids
@@ -238,43 +254,6 @@ class MeshDetector:
         db = db_shards if db_shards is not None else mesh.devices.shape[1]
         self.db = db
         self.device_ids = [int(d.id) for d in mesh.devices.flat]
-        if stream is not None:
-            from .stream import SliceCache, plan_slices
-            self._stream_bounds = plan_slices(
-                table, stream,
-                device_bytes=-(-table.device_nbytes() // max(db, 1)))
-            if self._stream_bounds is not None:
-                # uniform per-slice shard pad: one compiled sharded-
-                # join program family for the whole stream
-                max_rows = int(np.diff(self._stream_bounds).max())
-                self._shard_pad = max(1, -(-max_rows // db))
-                self._stream_resident = max(stream.resident, 2)
-                self._slice_cache = SliceCache(
-                    self._upload_mesh_slice,
-                    capacity=self._stream_resident, site="mesh")
-                # sharded HOST stacks per slice, built once: steady-
-                # state walks re-upload evicted slices constantly, and
-                # re-running the shard_arrays restack on every upload
-                # would run serially inside the dispatch watch (the
-                # StreamingDetector host-copy rationale; costs ≤ ~1×
-                # the device column bytes of host RAM)
-                self._host_slices: dict[int, ShardedTable] = {}
-                self._host_lock = threading.Lock()
-                # partition metadata only (row_offset fixes the db
-                # width); the real slice arrays live in the cache
-                self.st = ShardedTable(
-                    lo_tok=None, hi_tok=None, flags=None,
-                    row_offset=np.arange(db, dtype=np.int64),
-                    row_len=np.zeros(db, dtype=np.int64))
-                self._st_dev = None
-                from ..obs.perf import LEDGER
-                per_slice = (self._shard_pad * db
-                             * self._slice_row_bytes())
-                LEDGER.note_resident(
-                    "advisory_slice_resident",
-                    per_slice * min(max(stream.resident, 2),
-                                    self._stream_bounds.size - 1))
-                return
         # re-shard the advisory table for THIS mesh's db width — the
         # meshguard rebuild path gets table re-sharding for free by
         # constructing a fresh detector over the survivor mesh
@@ -289,42 +268,8 @@ class MeshDetector:
             flags=jax.device_put(self.st.flags, on_db),
             row_offset=self.st.row_offset, row_len=self.st.row_len)
 
-    def _slice_row_bytes(self) -> int:
-        t = self.table
-        return int(t.lo_tok.dtype.itemsize * t.lo_tok.shape[1] * 2
-                   + t.flags.dtype.itemsize)
-
-    def _host_mesh_slice(self, k: int) -> ShardedTable:
-        """Sharded host stacks for hash-range slice k, built once
-        (uniform shard pad across slices — see __init__)."""
-        with self._host_lock:
-            st = self._host_slices.get(k)
-            if st is None:
-                t = self.table
-                b = self._stream_bounds
-                r0, r1 = int(b[k]), int(b[k + 1])
-                st = shard_arrays(t.lo_tok[r0:r1], t.hi_tok[r0:r1],
-                                  t.flags[r0:r1], self.db,
-                                  pad=self._shard_pad)
-                self._host_slices[k] = st
-            return st
-
-    def _upload_mesh_slice(self, k: int):
-        """Ship slice k's (cached) sharded host stacks — the
-        graftstream SliceCache upload hook."""
-        st = self._host_mesh_slice(k)
-        on_db = NamedSharding(self.mesh, P("db"))
-        arrays = (jax.device_put(st.lo_tok, on_db),
-                  jax.device_put(st.hi_tok, on_db),
-                  jax.device_put(st.flags, on_db))
-        nbytes = st.lo_tok.nbytes + st.hi_tok.nbytes + st.flags.nbytes
-        return arrays, nbytes
-
     def close(self) -> None:
-        """Join the inner engine's worker threads and drop any
-        resident stream slices (idempotent)."""
-        if self._slice_cache is not None:
-            self._slice_cache.drop_all()
+        """Join the inner engine's worker threads (idempotent)."""
         self._inner.close()
 
     # ---- scheduler surface (detectd routes through these) --------------
@@ -347,35 +292,8 @@ class MeshDetector:
     def warmup(self, max_pairs: int = 1 << 18) -> int:
         """Near-no-op: mesh dispatch shapes depend on the per-cell
         pair partition, which the host-side LPT balancing decides per
-        batch — there is no fixed ladder to pre-compile. A streamed
-        mesh pre-touches its first resident slice pair so the first
-        request's walk starts warm."""
-        if self._slice_cache is not None:
-            for k in range(min(self._stream_resident,
-                               self._stream_bounds.size - 1)):
-                self._slice_cache.prefetch(k)
+        batch — there is no fixed ladder to pre-compile."""
         return 0
-
-    def prefetch_ranges(self, q_start, q_count) -> list[int]:
-        """graftfeed admission-aware prefetch, mesh edition: warm the
-        stream slices detectd's queued-request peek says the NEXT
-        dispatch will touch. No-op on a resident (unstreamed) mesh —
-        the whole table is already device-side. → issued slice
-        indices."""
-        if self._slice_cache is None or not self._stream_prefetch:
-            return []
-        from .stream import touched_slices
-        resident = set(self._slice_cache.resident())
-        issued: list[int] = []
-        for k in touched_slices(self._stream_bounds, q_start,
-                                q_count):
-            if k in resident:
-                continue
-            self._slice_cache.prefetch(k)
-            issued.append(k)
-            if len(issued) >= self._slice_cache.capacity:
-                break
-        return issued
 
     def dispatch_merged(self, preps, plan=_feed.PLAN_AUTO):
         """ONE mesh dispatch covering several prepared batches (the
@@ -434,8 +352,7 @@ class MeshDetector:
         single chip."""
         from ..log import get as _get_logger
         from ..obs import SLO
-        from ..obs import cost as _cost
-        from ..resilience import GUARD, DeviceError, failpoint
+        from ..resilience import GUARD, DeviceError
         inner = self._inner
         raw_fallback = host_fallback
 
@@ -471,23 +388,10 @@ class MeshDetector:
                     exc_info=True)
                 return host_fallback()
         # host-side routing BEFORE allow_device (the half-open-probe
-        # rule below): the resident path partitions the whole dispatch
-        # over the mesh; the streamed path clips it to the hash-range
-        # slices it touches and partitions per slice
-        part = plans = parts = None
+        # rule below): partition the whole dispatch over the mesh
         with span("detect.mesh.partition", dp=self.dp):
-            if self._stream_bounds is not None:
-                from .stream import clip_descriptors
-                plans = clip_descriptors(self._stream_bounds, q_start,
-                                         q_count, q_ver)
-                if not plans:
-                    return np.zeros(t_pad, np.int8)
-                parts = [partition_queries(self.st, p.q_start, p.q_count,
-                                           p.q_ver, self.dp)
-                         for p in plans]
-            else:
-                part = partition_queries(self.st, q_start, q_count,
-                                         q_ver, self.dp)
+            part = partition_queries(self.st, q_start, q_count, q_ver,
+                                     self.dp)
         # allow_device() LAST, immediately before the watch: when it
         # admits the half-open probe, the watch's exit is guaranteed
         # to record the probe's outcome (success, error, or timeout)
@@ -512,47 +416,36 @@ class MeshDetector:
                 # mesh-occupancy story
                 site = "redetect" if GUARD.blameless_active() \
                     else "mesh"
-                if plans is not None:
-                    # graftstream: walk the touched slices through the
-                    # double-buffered resident set — upload of slice
-                    # s+1 rides alongside the sharded join on slice s
-                    h_loc = 0
-                    bits, hit_notes = self._walk_mesh_slices(
-                        plans, parts, ver_dev, total, t_pad, site)
-                else:
-                    # per-dispatch accounting (occupancy vs the mesh's
-                    # total padded cell capacity, batch/compile
-                    # counters) — the mesh path launches its own join
-                    # and would otherwise go dark on the series the
-                    # single-chip dispatch path emits; traffic counts
-                    # only after the join actually completed
-                    t_total = int(part.t_loc) \
-                        * int(part.valid.shape[0]) \
-                        * int(part.valid.shape[1])
-                    # per-CELL hit buffers, sized by the inner
-                    # engine's hit-capacity policy over the cell pair
-                    # capacity (the hit rung is part of the compiled
-                    # shape)
-                    h_loc = inner._hit_capacity(part.t_loc)
+                # per-dispatch accounting (occupancy vs the mesh's
+                # total padded cell capacity, batch/compile counters) —
+                # the mesh path launches its own join and would
+                # otherwise go dark on the series the single-chip
+                # dispatch path emits; traffic counts only after the
+                # join actually completed
+                t_total = int(part.t_loc) * int(part.valid.shape[0]) \
+                    * int(part.valid.shape[1])
+                # per-CELL hit buffers, sized by the inner engine's
+                # hit-capacity policy over the cell pair capacity (the
+                # hit rung is part of the compiled shape)
+                h_loc = inner._hit_capacity(part.t_loc)
 
-                    # the scatter builds the result at the padded
-                    # dispatch length downstream slicing expects
-                    def _join():
-                        if h_loc:
-                            return sharded_csr_join_compact(
-                                self.mesh, self._st_dev, ver_dev,
-                                part, t_pad, h_loc)
-                        return sharded_csr_join(
+                # the scatter builds the result at the padded dispatch
+                # length downstream slicing expects
+                def _join():
+                    if h_loc:
+                        return sharded_csr_join_compact(
                             self.mesh, self._st_dev, ver_dev, part,
-                            t_pad), 0
-                    # shared synchronous-site accounting (stream.py):
-                    # compile bookkeeping + the ledger dispatch row
-                    from .stream import ledgered_sync_join
-                    bits, max_cell_hits = ledgered_sync_join(
-                        inner, _join, site, total, t_total,
-                        int(part.q_start.shape[-1]),
-                        int(ver_dev.shape[0]), h_loc, mesh=True)
-                    inner._account_traffic(total, t_total)
+                            t_pad, h_loc)
+                    return sharded_csr_join(
+                        self.mesh, self._st_dev, ver_dev, part,
+                        t_pad), 0
+                # synchronous-site accounting: compile bookkeeping +
+                # the ledger dispatch row
+                bits, max_cell_hits = ledgered_sync_join(
+                    inner, _join, site, total, t_total,
+                    int(part.q_start.shape[-1]),
+                    int(ver_dev.shape[0]), h_loc, mesh=True)
+                inner._account_traffic(total, t_total)
         except DeviceError:
             _get_logger("mesh").warning(
                 "sharded join failed; host-fallback join",
@@ -567,14 +460,6 @@ class MeshDetector:
                 self.guard.request_attribution()
             return host_fallback()
         METRICS.inc("trivy_tpu_mesh_dispatches_total", path="device")
-        if plans is not None:
-            # streamed: bits is already the merged global result and
-            # per-slice transfers were noted in the walk; adapt the
-            # hit budget from each slice's worst cell
-            for n_h, h_cap_k, t_total_k in hit_notes:
-                inner._note_hits(n_h, h_cap_k, site=site,
-                                 t_pad=t_total_k)
-            return bits
         if h_loc:
             # adapt the shared hit budget on the WORST cell — overflow
             # is per-cell, so the fullest buffer decides the next rung
@@ -588,66 +473,6 @@ class MeshDetector:
             # one int8 per real pair, as the dense result carries them
             _cost.ledgered_transfer("dense", float(total))
         return bits
-
-    def _walk_mesh_slices(self, plans, parts, ver_dev, total: int,
-                          t_pad: int, site: str):
-        """The graftstream slice walk, mesh edition (runs inside the
-        dispatch watch): each touched hash-range slice's db-sharded
-        arrays come off the double-buffered resident set (the NEXT
-        slice's upload is prefetched before this slice's collective
-        launches), the per-slice sharded join runs exactly like a
-        resident dispatch, and the slice results concat-merge into one
-        global result bit-identical to the unstreamed join.
-        → (merged bits, [(max cell hits, h_cap, t_total)] notes)."""
-        from ..obs import cost as _cost
-        from .stream import ledgered_sync_join, merge_slice_bits
-        inner = self._inner
-        results: list = []
-        hit_notes: list = []
-        t_total_sum = 0
-        for i, (plan, part) in enumerate(zip(plans, parts)):
-            dev = self._slice_cache.get(plan.idx)
-            if i + 1 < len(plans):
-                self._slice_cache.prefetch(plans[i + 1].idx)
-            st = ShardedTable(dev[0], dev[1], dev[2],
-                              self.st.row_offset, self.st.row_len)
-            t_total = int(part.t_loc) * int(part.valid.shape[0]) \
-                * int(part.valid.shape[1])
-            t_total_sum += t_total
-            h_loc = inner._hit_capacity(part.t_loc)
-
-            def _join():
-                if h_loc:
-                    return sharded_csr_join_compact(
-                        self.mesh, st, ver_dev, part, plan.total,
-                        h_loc)
-                return sharded_csr_join(self.mesh, st, ver_dev, part,
-                                        plan.total), 0
-            bits_k, max_hits = ledgered_sync_join(
-                inner, _join, site, plan.total, t_total,
-                int(part.q_start.shape[-1]), int(ver_dev.shape[0]),
-                h_loc, mesh=True)
-            if h_loc:
-                hit_notes.append((max_hits, h_loc, t_total))
-            if isinstance(bits_k, CompactBits):
-                _cost.ledgered_transfer("compact",
-                                        float(bits_k.pair_idx.nbytes
-                                              + bits_k.bits.nbytes))
-            else:
-                _cost.ledgered_transfer(
-                    "dense", float(np.asarray(bits_k).nbytes))
-            results.append((plan, bits_k))
-        # tail prefetch: the next dispatch over the same hash span
-        # starts back at the walk's first slice — ship it into the
-        # just-freed buffer before that dispatch needs it
-        if len(plans) > 1 or \
-                plans[0].idx not in self._slice_cache.resident():
-            self._slice_cache.prefetch(plans[0].idx)
-        # ONE traffic observation per logical mesh dispatch; the
-        # ledger above carries the per-slice collective launches
-        inner._account_traffic(total, t_total_sum)
-        with span("detect.mesh.scatter", slices=len(results)):
-            return merge_slice_bits(results, t_pad), hit_notes
 
     def _bits(self, prep) -> np.ndarray:
         inner = self._inner

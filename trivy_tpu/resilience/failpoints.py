@@ -43,8 +43,6 @@ SITES = (
     "detect.compile",     # detect/engine.py _launch, new-shape compiles
     "detect.query_upload",  # detect/feed.py upload_queries (graftfeed
     #                         staged/inline query-column H2D transfer)
-    "stream.prefetch",    # parallel/stream.py SliceCache.prefetch
-    #                       (graftstream/graftfeed advisory warmups)
     "cache.backend",      # fanal/cache.py FSCache blob/artifact IO
     "cache.redis",        # fanal/redis_cache.py shared-backend IO
     "cache.s3",           # fanal/s3_cache.py shared-backend IO

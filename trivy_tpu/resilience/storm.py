@@ -73,12 +73,9 @@ _SINGLE_FAULTS = (
     ("detect.device_get", "error"), ("detect.device_get", "flaky"),
     ("detect.compile", "error"), ("rpc.scan", "slow"),
     # graftfeed: a wedged/failed staged query upload must degrade to
-    # the host join (the stage runs under its own watch); a tripped
-    # slice prefetch may only cost a cold upload — no hang mode for
-    # it, because prefetch is advisory and fires outside any watchdog
+    # the host join (the stage runs under its own watch)
     ("detect.query_upload", "error"), ("detect.query_upload", "hang"),
     ("detect.query_upload", "flaky"),
-    ("stream.prefetch", "error"), ("stream.prefetch", "flaky"),
 )
 _MESH_FAULTS = (
     ("detect.mesh", "error"), ("detect.mesh", "hang"),

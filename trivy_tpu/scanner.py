@@ -31,7 +31,7 @@ if TYPE_CHECKING:
 class LocalScanner:
     def __init__(self, cache, table: AdvisoryTable,
                  sched: "SchedOptions | None" = None,
-                 mesh=None, mesh_guard=None, memo=None, stream=None):
+                 mesh=None, mesh_guard=None, memo=None):
         self.cache = cache
         self.table = table
         # graftmemo: content-addressed detection-result memo (an open
@@ -45,27 +45,13 @@ class LocalScanner:
         # `mesh="host"` is the zero-survivor degraded detector — same
         # surface, every join host-side — so the meshguard grow path
         # can swap a real mesh back in through the same drain.
-        # graftstream (stream=StreamOptions): a table whose per-device
-        # footprint exceeds the budget streams through a double-
-        # buffered resident slice pair — on the mesh AND single-chip
-        # paths; a within-budget table keeps the resident detector
-        # byte-for-byte unchanged (plan_slices decides).
         if mesh is not None:
             from .parallel.mesh import MeshDetector
             self.detector = MeshDetector(
                 table, None if mesh == "host" else mesh,
-                guard=mesh_guard, stream=stream)
+                guard=mesh_guard)
         else:
-            bounds = None
-            if stream is not None:
-                from .parallel.stream import (StreamingDetector,
-                                              plan_slices)
-                bounds = plan_slices(table, stream)
-            if bounds is not None:
-                self.detector = StreamingDetector(table, stream,
-                                                  bounds=bounds)
-            else:
-                self.detector = BatchDetector(table)
+            self.detector = BatchDetector(table)
         # detectd: when the owner passes SchedOptions (the scan server
         # does by default), detection routes through the shared
         # coalescing scheduler so concurrent requests merge into

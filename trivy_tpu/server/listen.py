@@ -48,9 +48,8 @@ class MeshOptions:
     """Mesh-mode knobs (server flags --mesh-devices, --mesh-db-shards,
     --mesh-min-devices, --mesh-rebuild-cooldown-ms,
     --mesh-probe-timeout-ms, --mesh-hosts,
-    --mesh-host-loss-window-ms, --table-device-budget-mb,
-    --table-stream-slices). devices=0 keeps the single-chip path;
-    the table-streaming knobs apply there too."""
+    --mesh-host-loss-window-ms). devices=0 keeps the single-chip
+    path."""
     devices: int = 0          # mesh size; 0 = single-chip detect path
     db_shards: int = 1        # preferred db width (shrink re-fits it)
     min_devices: int = 1      # survivors below this → host join
@@ -63,16 +62,6 @@ class MeshOptions:
     # per-chip behavior.
     hosts: int = 0
     host_loss_window_ms: float = 250.0
-    # graftstream: stream the advisory table through a double-buffered
-    # resident slice pair once its per-device footprint exceeds the
-    # budget (0 = auto off the graftprof hbm_bytes view; slices > 0
-    # forces a slice count)
-    table_device_budget_mb: float = 0.0
-    table_stream_slices: int = 0
-    # graftfeed: admission-aware slice prefetch — detectd peeks its
-    # queue and warms the slices the next dispatch will touch
-    # (--stream-prefetch / --no-stream-prefetch)
-    stream_prefetch: bool = True
 
 
 class ServerState:
@@ -139,19 +128,6 @@ class ServerState:
         # the latest breaker-recovery rebuild thread; close() joins it
         # (bounded) so a recovery swap can't outlive the server
         self._recover_thread: threading.Thread | None = None
-        # graftstream: when mesh_opts carries streaming knobs (or just
-        # defaults — the auto budget comes off graftprof's hbm view),
-        # every detector this state builds may stream the advisory
-        # table through a double-buffered resident slice pair instead
-        # of holding it device-whole. plan_slices() decides per table;
-        # a table within budget keeps the resident path unchanged.
-        self.stream_opts = None
-        if mesh_opts is not None:
-            from ..parallel.stream import StreamOptions
-            self.stream_opts = StreamOptions(
-                device_budget_mb=mesh_opts.table_device_budget_mb,
-                slices=mesh_opts.table_stream_slices,
-                prefetch=mesh_opts.stream_prefetch)
         if mesh_opts is not None and mesh_opts.devices:
             import jax
 
@@ -183,8 +159,7 @@ class ServerState:
                                      sched=self.detect_opts,
                                      mesh=self._mesh,
                                      mesh_guard=self.mesh_guard,
-                                     memo=self.memo,
-                                     stream=self.stream_opts)
+                                     memo=self.memo)
         # redetectd: on a DB hot swap, sweep the memo's known blobs
         # through the pure detect path in the background so fresh
         # entries exist under the new db_version before users rescan
@@ -383,8 +358,7 @@ class ServerState:
                                        sched=self.detect_opts,
                                        mesh=build_mesh,
                                        mesh_guard=self.mesh_guard,
-                                       memo=self.memo,
-                                       stream=self.stream_opts)
+                                       memo=self.memo)
             # digest outside the lock too (first computation walks the
             # whole table); cached on the table object afterwards
             new_version = build_table.content_digest()
@@ -667,14 +641,6 @@ class Handler(BaseHTTPRequestHandler):
                         },
                     },
                 }
-                # graftstream: slice plan + resident set when the
-                # serving detector streams its advisory table (the
-                # single-chip StreamingDetector exposes status();
-                # resident detectors have nothing to report)
-                stream_status = getattr(
-                    self.state.scanner.detector, "status", None)
-                if callable(stream_status):
-                    payload["stream"] = stream_status()
                 # graftmemo: backend + known-blob count, and the
                 # redetectd sweep's progress (phase, done/total,
                 # target db_version)
