@@ -87,22 +87,57 @@ class Status:
 
 # --- helpers ---
 
+_ZEROS = ("", None, [], {}, 0, False)  # what omitempty drops
+_PLAIN = (str, int, bool, type(None))  # exact types emitted as they are
+
+
 def _strip_empty(v: Any) -> Any:
     """Drop Go-zero values recursively (json omitempty emulation)."""
+    t = type(v)
+    if t in _PLAIN:
+        return v
+    if t is list or t is tuple:
+        return [_strip_empty(x) for x in v]
+    if t is dict:
+        return _strip_dict(v)
+    if isinstance(v, JsonMixin):
+        return v.to_json()
+    # subclasses (str-enums, dict and list subclasses), floats, and
+    # dataclasses outside JsonMixin
     if dataclasses.is_dataclass(v) and not isinstance(v, type):
         return v.to_json()
     if isinstance(v, dict):
-        out = {}
-        for k, val in v.items():
-            sv = _strip_empty(val)
-            if sv not in ("", None, [], {}, 0, False) or sv is True:
-                out[k] = sv
-        return out
+        return _strip_dict(v)
     if isinstance(v, (list, tuple)):
         return [_strip_empty(x) for x in v]
     if isinstance(v, float) and v.is_integer():
         return int(v)  # Go marshals float64(5) as "5", not "5.0"
     return v
+
+
+def _strip_dict(v: dict) -> dict:
+    out = {}
+    for k, val in v.items():
+        sv = _strip_empty(val)
+        if sv not in _ZEROS or sv is True:
+            out[k] = sv
+    return out
+
+
+# concrete class -> its field plan, built on the class's first to_json
+_PLANS: dict = {}
+
+
+def _plan(cls) -> tuple:
+    """(attribute, JSON name, raw, keep_zero) per serialized field, in
+    `dataclasses.fields` order. The class attributes it reads are fixed
+    once the class is defined, so one plan serves every instance."""
+    plan = tuple(
+        (f.name, cls._json_names.get(f.name, _pascal(f.name)),
+         f.name in cls._json_raw, f.name in cls._keep_zero)
+        for f in dataclasses.fields(cls) if f.name not in cls._json_skip)
+    _PLANS[cls] = plan
+    return plan
 
 
 class JsonMixin:
@@ -112,19 +147,17 @@ class JsonMixin:
     _json_raw: tuple = ()   # fields emitted verbatim (no zero-stripping)
 
     def to_json(self) -> dict:
+        cls = type(self)
+        plan = _PLANS.get(cls)
+        if plan is None:
+            plan = _plan(cls)
         out = {}
-        for f in dataclasses.fields(self):
-            if f.name in self._json_skip:
-                continue
-            v = getattr(self, f.name)
-            name = self._json_names.get(f.name, _pascal(f.name))
-            sv = v if f.name in self._json_raw else _strip_empty(v)
-            if f.name in self._keep_zero:
-                out[name] = sv
-                continue
-            if sv in ("", None, [], {}, 0, False):
-                continue
-            out[name] = sv
+        for attr, name, raw, keep_zero in plan:
+            v = getattr(self, attr)
+            if not raw and type(v) not in _PLAIN:
+                v = _strip_empty(v)
+            if keep_zero or v not in _ZEROS:
+                out[name] = v
         return out
 
 
