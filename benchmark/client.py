@@ -93,17 +93,18 @@ def run_loop(url, items, scanners, t0, seconds):
 def main() -> int:
     job = _hear()
     sys.path.insert(0, job["root"])
-    from benchmark import images, synth
+    from benchmark import images, spec, synth
     cell, seed, client = job["cell"], job["seed"], job["client"]
     scanners = tuple(job["scanners"])
-    adv = synth.advisories(seed, **job["table"])
+    family = spec.family(job["root"], job["family"])
+    adv = synth.advisories(seed, family=family, **job["table"])
     warm, mine = client_specs(adv, cell, seed, client)
     os.makedirs(job["dir"], exist_ok=True)
 
-    def build(spec):
-        path = os.path.join(job["dir"], spec.tag.split("/")[1]
+    def build(img):
+        path = os.path.join(job["dir"], img.tag.split("/")[1]
                             .replace(":", "_") + ".tar")
-        images.write_image(path, images.image_layers(spec), spec.tag)
+        images.write_image(path, images.image_layers(img, family), img.tag)
         return path
 
     warm_items = [(-1, build(s)) for s in warm]

@@ -11,9 +11,9 @@ line with
   that differ from the plain reference, scans never answered); the
   lower readings of the limits;
 - the control's readings: the reference at the precision below the one
-  the configuration states (versions compared by major.minor only,
-  `reference.apk_cmp(precision="minor")`), put in the program's place on
-  the same scans; the upper readings.
+  the configuration states (the family's `cmp(precision="minor")`;
+  Alpine's compares versions by major.minor only), put in the program's
+  place on the same scans; the upper readings.
 
 The benchmark's own runs never run it. PERF.md gives the readings and
 the limits set from them.

@@ -13,13 +13,11 @@ import io
 import json
 import tarfile
 
-OS_RELEASE = b'NAME="Alpine Linux"\nID=alpine\nVERSION_ID=3.19.1\n'
+from .families import alpine
 
-
-def apk_installed(pkgs) -> bytes:
-    """The apk database (`lib/apk/db/installed`) for [(name, version)]."""
-    return "".join(f"P:{nm}\nV:{ver}\nA:x86_64\no:{nm}\nL:MIT\n\n"
-                   for nm, ver in pkgs).encode()
+# the names tests have always imported: the Alpine family's
+OS_RELEASE = alpine.OS_RELEASE
+apk_installed = alpine.apk_installed
 
 
 def _tar(files) -> bytes:
@@ -58,10 +56,11 @@ def _write_tar(path: str, members) -> None:
             tf.addfile(ti, io.BytesIO(data))
 
 
-def image_layers(spec) -> list:
-    """An image's layers: the apk layer, then its text layer if any."""
-    layers = [[("etc/os-release", OS_RELEASE),
-               ("lib/apk/db/installed", apk_installed(spec.pkgs))]]
+def image_layers(spec, family=alpine) -> list:
+    """An image's layers: the OS layer (the family's `os-release` and
+    package database), then its text layer if any."""
+    layers = [[("etc/os-release", family.OS_RELEASE),
+               *family.package_files(spec.pkgs)]]
     if spec.text_files:
         layers.append(list(spec.text_files))
     return layers

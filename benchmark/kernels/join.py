@@ -10,7 +10,8 @@ the scans a window completed: what any implementation has to move.
 
 Counted from the traffic and the advisories, never from the program's
 padded shapes, so no change to padding, dedup or row reuse can raise
-it.
+it. An image's keys are (source name, source version), as the program
+joins them: the binaries of one source share its rows and its version.
 """
 
 KEY_WIDTH = 40

@@ -15,7 +15,7 @@ import tempfile
 import threading
 import time
 
-from . import images, synth, traffic
+from . import images, spec, synth, traffic
 from .window import Scan
 
 
@@ -75,17 +75,18 @@ def run(root: str, cell: dict, seed: int, seconds: float, window):
     conf, traf = cell["config_doc"], cell["traffic_doc"]
     scanners = tuple(conf["scanners"])
     workers = traf["clients"]
-    adv = synth.advisories(seed, **conf["table"])
+    family = spec.family(root, cell["family"])
+    adv = synth.advisories(seed, family=family, **conf["table"])
     table = synth.program_table(adv)
     pool = traffic.image_specs(adv, traf["image"], seed, 0, traf["pool"],
                                "p")
     scanner = LocalScanner(MemoryCache(), table)
     with tempfile.TemporaryDirectory(prefix="bench-") as tmp:
         paths = []
-        for k, spec in enumerate(pool):
+        for k, img in enumerate(pool):
             paths.append(os.path.join(tmp, f"p{k}.tar"))
-            images.write_image(paths[-1], images.image_layers(spec),
-                               spec.tag)
+            images.write_image(paths[-1], images.image_layers(img, family),
+                               img.tag)
         try:
             def warm_round(_r):
                 t0 = time.monotonic()
@@ -111,7 +112,7 @@ def run(root: str, cell: dict, seed: int, seconds: float, window):
             # carried over from an earlier scan of the same image
             scanned = _bytes_scanned() - n1
             want = sum(len(data) for r in records
-                       for layer in images.image_layers(r.spec)
+                       for layer in images.image_layers(r.spec, family)
                        for _path, data in layer)
             window.note(f"# secret bytes scanned in the window: "
                         f"{scanned:.0f}, the {len(records)} scans' files "

@@ -3,16 +3,19 @@
 Imports nothing of the program and takes nothing it made. It works from
 the advisories of `synth.py` and the image contents of `images.py`:
 
-- vulnerabilities: for each installed package, every advisory of its
-  name whose fixed version sorts above the installed one, by
-  apk-tools' version order (written out below, not the program's
-  tokens or join);
+- vulnerabilities: for each (source name, source version) an image
+  installs, every advisory of that source whose fixed version sorts
+  above the installed one, or that is unfixed, reported once for each
+  binary package the source stands for. The version order and the
+  source-to-binary mapping are the OS package family's
+  (`families/<family>.py`), written out there, not the program's
+  tokens or join;
 - secrets: every AWS access key id in a text file, by the rule's own
   regular expression, with its line and its censored line.
 
-`precision="minor"` is the control: versions compared by major.minor
-only, the cut a two-token version key would make. It must come out as
-not correct.
+`precision="minor"` is the control: the family's cut of its order
+(Alpine: major.minor only, the cut a two-token version key would make).
+It must come out as not correct.
 """
 
 from __future__ import annotations
@@ -22,67 +25,21 @@ import re
 
 import numpy as np
 
-_VERSION = re.compile(
-    r"^(\d+(?:\.\d+)*)([a-z]?)((?:_(?:alpha|beta|pre|rc|cvs|svn|git|hg|p)"
-    r"\d*)*)(?:-r(\d+))?$")
-# apk-tools' suffix order; a version without a suffix sits between rc
-# and cvs
-_SUFFIX = ["alpha", "beta", "pre", "rc", "", "cvs", "svn", "git", "hg", "p"]
-_NO_SUFFIX = _SUFFIX.index("")
+from .families import alpine
+
+# the name tests have always imported: the Alpine family's order
+apk_cmp = alpine.cmp
 
 
-def _parse(v: str):
-    m = _VERSION.match(v)
-    if m is None:
-        raise ValueError(f"not an apk version: {v!r}")
-    parts = m.group(1).split(".")
-    suffixes = [(_SUFFIX.index(re.match(r"[a-z]+", s).group(0)),
-                 int(re.sub(r"[a-z]+", "", s) or 0))
-                for s in m.group(3).split("_")[1:]]
-    return parts, m.group(2), suffixes, int(m.group(4) or 0)
-
-
-def _cmp_part(a: str, b: str, first: bool) -> int:
-    if not first and ((len(a) > 1 and a[0] == "0")
-                      or (len(b) > 1 and b[0] == "0")):
-        # a leading zero makes both decimal fractions
-        x, y = a.rstrip("0"), b.rstrip("0")
-    else:
-        x, y = int(a), int(b)
-    return (x > y) - (x < y)
-
-
-def apk_cmp(a: str, b: str, precision: str = "full") -> int:
-    """-1, 0 or 1 as apk-tools orders versions `a` and `b`."""
-    pa, la, sa, ra = _parse(a)
-    pb, lb, sb, rb = _parse(b)
-    if precision == "minor":
-        pa, pb = pa[:2], pb[:2]
-    for i in range(max(len(pa), len(pb))):
-        if i == len(pa) or i == len(pb):
-            return -1 if i == len(pa) else 1
-        c = _cmp_part(pa[i], pb[i], i == 0)
-        if c:
-            return c
-    if precision == "minor":
-        return 0
-    if la != lb:
-        return -1 if la < lb else 1
-    for i in range(max(len(sa), len(sb))):
-        x = sa[i] if i < len(sa) else (_NO_SUFFIX, 0)
-        y = sb[i] if i < len(sb) else (_NO_SUFFIX, 0)
-        if x != y:
-            return -1 if x < y else 1
-    return (ra > rb) - (ra < rb)
-
-
-def version_ranks(versions, precision: str = "full") -> dict:
-    """→ {version: rank}, equal ranks for versions that compare equal."""
+def version_ranks(versions, precision: str = "full",
+                  cmp=apk_cmp) -> dict:
+    """→ {version: rank}, equal ranks for versions that compare equal
+    by `cmp`."""
     order = sorted(set(versions), key=functools.cmp_to_key(
-        lambda a, b: apk_cmp(a, b, precision)))
+        lambda a, b: cmp(a, b, precision)))
     ranks, r = {}, 0
     for i, v in enumerate(order):
-        if i and apk_cmp(order[i - 1], v, precision):
+        if i and cmp(order[i - 1], v, precision):
             r += 1
         ranks[v] = r
     return ranks
@@ -90,23 +47,30 @@ def version_ranks(versions, precision: str = "full") -> dict:
 
 class VulnReference:
     """Vulnerability findings of any image over one table of
-    advisories (a `synth.Advisories`)."""
+    advisories (a `synth.Advisories`), by its family's version order
+    and source-to-binary mapping."""
 
     def __init__(self, adv, precision: str = "full"):
+        self._family = adv.family
         ranks = version_ranks(adv.fixed_pool + adv.installed_pool,
-                              precision)
+                              precision, adv.family.cmp)
         self._rank = ranks
-        self._by_pkg = {name: (ids, np.array([ranks[f] for f in fixed]))
+        # an unfixed advisory ("") ranks above every installed version
+        unfixed = len(ranks)
+        self._by_pkg = {name: (ids, np.array([ranks[f] if f else unfixed
+                                              for f in fixed]))
                         for name, (ids, fixed) in adv.by_pkg.items()}
 
     def findings(self, pkgs) -> list:
-        """pkgs: [(name, installed version)] → sorted
-        [(name, version, vulnerability id)]."""
+        """pkgs: [(source name, source version)] → sorted [(binary
+        name, binary version, vulnerability id)] for every binary that
+        each source key stands for."""
         out = []
         for name, ver in pkgs:
             ids, fixed = self._by_pkg.get(name, ((), np.zeros(0)))
-            for j in np.flatnonzero(self._rank[ver] < fixed):
-                out.append((name, ver, ids[j]))
+            hit = [ids[j] for j in np.flatnonzero(self._rank[ver] < fixed)]
+            for b_name, b_ver in self._family.binaries(name, ver):
+                out += [(b_name, b_ver, vid) for vid in hit]
         return sorted(out)
 
 

@@ -21,7 +21,7 @@ import threading
 import time
 
 from . import client as client_mod
-from . import synth
+from . import spec, synth
 from .window import Scan
 
 
@@ -126,9 +126,12 @@ def run(root: str, cell: dict, seed: int, seconds: float, window) -> list:
                 kids.append(Child(root, {
                     "root": root, "cell": tcell, "seed": seed, "client": c,
                     "dir": os.path.join(tmp, f"c{c}"),
-                    "scanners": conf["scanners"], "table": conf["table"]},
+                    "scanners": conf["scanners"], "table": conf["table"],
+                    "family": cell["family"]},
                     os.path.join(tmp, f"client{c}.log")))
-            adv = synth.advisories(seed, **conf["table"])
+            adv = synth.advisories(
+                seed, family=spec.family(root, cell["family"]),
+                **conf["table"])
             table = synth.program_table(adv)
             phase("table built")
             with server(table, os.path.join(tmp, "srv-cache"),

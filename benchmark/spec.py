@@ -2,12 +2,15 @@
 
 Everything is looked up from `BENCHMARK.json` at the checkout's root:
 a cell names its configuration (whose `file` is read) and its traffic
-(`benchmark/traffic/<traffic>.json`); a metric applies to a cell when
+(`benchmark/traffic/<traffic>.json`), and the configuration names its
+OS package family (`benchmark/families/<family>.py`, `"family"`,
+default alpine); a metric applies to a cell when
 its `workloads` lists the cell or it has no `workloads`; a per-layer
 metric is read by `benchmark/layer_metrics/<metric>.py`, a kernel's
 least bytes come from `benchmark/kernels/<kernel>.py`, and peaks from
 `benchmark/peaks.json` by `device_kind`. Adding a cell, a
-configuration or a metric adds files and entries and edits none.
+configuration, a family or a metric adds files and entries and edits
+none.
 """
 
 from __future__ import annotations
@@ -15,6 +18,20 @@ from __future__ import annotations
 import importlib.util
 import json
 import os
+
+
+def family(root: str, name: str):
+    """The OS package family module `benchmark/families/<name>.py` of
+    the checkout at `root`."""
+    return _load(os.path.join(root, "benchmark", "families", name + ".py"),
+                 f"benchmark.families.{name}")
+
+
+def _load(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 class Bench:
@@ -25,7 +42,8 @@ class Bench:
             self.doc = json.load(f)
 
     def cell(self, name: str) -> dict:
-        """→ the cell's entry with its `config_doc` and `traffic_doc`."""
+        """→ the cell's entry with its `config_doc`, `traffic_doc` and
+        `family`, the name of its configuration's OS package family."""
         cells = {w["name"]: w for w in self.doc["workloads"]}
         if name not in cells:
             raise SystemExit(f"no workload {name!r} in BENCHMARK.json "
@@ -37,6 +55,7 @@ class Bench:
         with open(os.path.join(self.dir, "traffic",
                                cell["traffic"] + ".json")) as f:
             cell["traffic_doc"] = json.load(f)
+        cell["family"] = cell["config_doc"].get("family", "alpine")
         return cell
 
     def metrics(self, cell: str, kind: str) -> list:
@@ -46,12 +65,8 @@ class Bench:
                 if cell in m.get("workloads", [cell])]
 
     def _module(self, sub: str, name: str):
-        path = os.path.join(self.dir, sub, name + ".py")
-        spec = importlib.util.spec_from_file_location(
-            f"benchmark.{sub}.{name}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
+        return _load(os.path.join(self.dir, sub, name + ".py"),
+                     f"benchmark.{sub}.{name}")
 
     def reader(self, metric: str):
         return self._module("layer_metrics", metric)

@@ -1,11 +1,11 @@
 """The advisory table of a deployment, made from the run's seed.
 
 Copied from `trivy_tpu/db/synth.py` (PR 21) so that no program PR can
-move the yardstick: 30,000 package names with Zipf(1.7) bucket sizes
-clipped to [1, 64], plus one `linux-lts` source package carrying 4,000
-advisory rows: 204,795 interval rows. Fixed versions skew low and the
-installed pool skews high, so an 80-package image hits ~30 CVEs, the
-density of real images.
+move the yardstick: 30,000 source package names with Zipf(1.7) bucket
+sizes clipped to [1, 64], plus one skewed source package (Alpine's
+`linux-lts`) carrying 4,000 advisory rows: 204,795 interval rows. The
+versions, the fixed version of each row and the source and ecosystem
+are the OS package family's (`families/<family>.py`).
 
 One change from the copy: the multiset of bucket sizes is the one seed
 7 drew in PR 21, for every run seed. The seed deals those sizes out to
@@ -19,13 +19,14 @@ import functools
 
 import numpy as np
 
+from .families import alpine
+
 N_PKG_NAMES = 30_000
-SOURCE = "alpine 3.19"
-ECOSYSTEM = "alpine"
-SKEW_PKG = "linux-lts"
 SKEW_ROWS = 4000
-POOL_VERSIONS = 2000
 SHAPE_SEED = 7
+# the names tests have always imported: the Alpine family's
+SOURCE, ECOSYSTEM, SKEW_PKG = alpine.SOURCE, alpine.ECOSYSTEM, alpine.SKEW_PKG
+synth_versions = alpine.versions
 
 
 def rng_for(seed: int, *stream: int) -> np.random.Generator:
@@ -34,40 +35,30 @@ def rng_for(seed: int, *stream: int) -> np.random.Generator:
     return np.random.default_rng([int(seed) % (1 << 63), *stream])
 
 
-def synth_versions(rng, n=POOL_VERSIONS, major_lo=0, major_hi=9) -> list:
-    out = []
-    for _ in range(n):
-        v = (f"{rng.integers(major_lo, major_hi + 1)}."
-             f"{rng.integers(0, 31)}.{rng.integers(0, 31)}")
-        r = rng.random()
-        if r < 0.15:
-            v += f"_p{rng.integers(1, 10)}"
-        elif r < 0.3:
-            v += ["_rc1", "_git20230101", "a"][int(rng.integers(0, 3))]
-        v += f"-r{rng.integers(0, 21)}"
-        out.append(v)
-    return out
-
-
 @functools.lru_cache(maxsize=1)
 def bucket_sizes() -> np.ndarray:
     """The advisory count of each of the 30,000 packages, as the PR 21
-    smoke drew them from seed 7 (same draw order)."""
+    smoke drew them from seed 7 (same draw order): two apk version
+    pools, then the Zipf draw. It sets the table's shape for every
+    family."""
     rng = np.random.default_rng(SHAPE_SEED)
-    synth_versions(rng, major_lo=0, major_hi=6)
-    synth_versions(rng, major_lo=4, major_hi=9)
+    alpine.pools(rng)
     return np.clip(rng.zipf(1.7, N_PKG_NAMES), 1, 64)
 
 
 class Advisories:
-    """The deployment's advisories as plain data: for each package
-    name, its vulnerability ids and fixed versions (vulnerable iff the
-    installed version sorts below the fixed one)."""
+    """The deployment's advisories as plain data: for each source
+    package name, its vulnerability ids and fixed versions (vulnerable
+    iff the installed version sorts below the fixed one, or the fixed
+    version is "", unfixed), and the family (`families/<family>.py`)
+    whose versions and packages they are."""
 
-    def __init__(self, by_pkg: dict, fixed_pool: list, installed_pool: list):
+    def __init__(self, by_pkg: dict, fixed_pool: list, installed_pool: list,
+                 family=alpine):
         self.by_pkg = by_pkg                # name -> (ids, fixed versions)
         self.fixed_pool = fixed_pool
         self.installed_pool = installed_pool
+        self.family = family
 
     @property
     def n_rows(self) -> int:
@@ -76,35 +67,34 @@ class Advisories:
     def raw(self):
         """→ the program's RawAdvisory list, in table order."""
         from trivy_tpu.db.table import RawAdvisory
-        return [RawAdvisory(source=SOURCE, ecosystem=ECOSYSTEM,
+        fam = self.family
+        return [RawAdvisory(source=fam.SOURCE, ecosystem=fam.ECOSYSTEM,
                             pkg_name=name, vuln_id=vid, fixed_version=fv)
                 for name, (ids, fixed) in self.by_pkg.items()
                 for vid, fv in zip(ids, fixed)]
 
 
 def advisories(seed: int, n_names: int = N_PKG_NAMES,
-               skew_rows: int = SKEW_ROWS) -> Advisories:
-    """The table from `seed`. Smaller `n_names`/`skew_rows` are for the
-    CPU tests only."""
+               skew_rows: int = SKEW_ROWS, family=alpine) -> Advisories:
+    """The table of `family` from `seed`. Smaller `n_names`/`skew_rows`
+    are for the CPU tests only."""
     rng = rng_for(seed, 0)
-    fixed_pool = synth_versions(rng, major_lo=0, major_hi=6)
-    installed_pool = synth_versions(rng, major_lo=4, major_hi=9)
+    fixed_pool, installed_pool = family.pools(rng)
     sizes = rng.permutation(bucket_sizes())[:n_names]
-    fixed_idx = rng.integers(0, len(fixed_pool), int(sizes.sum()))
+    fixed = family.fixed(rng, fixed_pool, int(sizes.sum()))
     by_pkg = {}
     k = 0
     for i in range(n_names):
         n = int(sizes[i])
         by_pkg[f"pkg{i:05d}"] = (
             [f"CVE-2024-{i % 10000:04d}-{j}" for j in range(n)],
-            [fixed_pool[int(x)] for x in fixed_idx[k:k + n]])
+            fixed[k:k + n])
         k += n
     # the skewed bucket: mostly-patched old advisories (low fix versions)
-    by_pkg[SKEW_PKG] = (
+    by_pkg[family.SKEW_PKG] = (
         [f"CVE-2019-{j:05d}" for j in range(skew_rows)],
-        [fixed_pool[int(x)] for x in
-         rng.integers(0, len(fixed_pool), skew_rows)])
-    return Advisories(by_pkg, fixed_pool, installed_pool)
+        family.fixed(rng, fixed_pool, skew_rows))
+    return Advisories(by_pkg, fixed_pool, installed_pool, family)
 
 
 def program_table(adv: Advisories):

@@ -18,7 +18,7 @@ returns.
                  a checkout, which compiles: `warmup_cold_quiet_rounds`)
   pool           image entry: images cycled through, each scan on a
                  fresh cache
-  image          pkgs [lo, hi]: apk packages per image, spread evenly
+  image          pkgs [lo, hi]: source packages per image, spread evenly
                  over the range; text_files, text_file_bytes: a second
                  layer of seeded text with one AWS key planted per image
 
