@@ -66,8 +66,10 @@ def test_archives_cover_the_highest_rate_over_the_window(seconds, want):
 
 # ---- lookup by name --------------------------------------------------------
 
-def test_every_cell_metric_and_reader_is_found_by_name():
-    bench = Bench(ROOT)
+def check_cells(root: str) -> None:
+    """Every cell of the checkout at `root` finds its configuration,
+    traffic, metrics and readers by name."""
+    bench = Bench(root)
     for w in bench.doc["workloads"]:
         cell = bench.cell(w["name"])
         assert cell["config_doc"]["entry"] in ("server", "image")
@@ -77,13 +79,17 @@ def test_every_cell_metric_and_reader_is_found_by_name():
         assert callable(bench.reader(m["name"]).read)
     # every reader is a metric's: none is left behind by a cell
     assert sorted(f[:-3] for f in os.listdir(os.path.join(
-        ROOT, "benchmark", "layer_metrics")) if f.endswith(".py")) == \
+        root, "benchmark", "layer_metrics")) if f.endswith(".py")) == \
         sorted(m["name"] for m in bench.doc["per_layer"])
     for k in ("join", "shiftor"):
         assert callable(bench.kernel(k).least_bytes)
     assert bench.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
     with pytest.raises(SystemExit):
         bench.peaks("cpu")
+
+
+def test_every_cell_metric_and_reader_is_found_by_name():
+    check_cells(ROOT)
 
 
 def test_a_new_cell_needs_no_edit_of_an_existing_file(tmp_path):

@@ -5,7 +5,9 @@ archives, the reference's findings at both precisions and the join's
 least bytes must read exactly as they did. Then the promise that a new
 family is files only: a stand-in family, with a source-to-binary
 mapping and unfixed advisories, is taken by adding one family module,
-one configuration and one traffic file to a copy of `benchmark/`."""
+one configuration and one traffic file to a copy of `benchmark/`; made
+a shipped cell of such a copy, it passes every check that goes over the
+shipped cells."""
 
 from __future__ import annotations
 
@@ -21,6 +23,8 @@ import pytest
 
 from benchmark import images, reference, spec, synth, traffic
 from benchmark.spec import Bench
+from family_checks import check_shipped_families
+from test_benchmark_harness import check_cells
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -211,10 +215,42 @@ def test_a_new_family_needs_no_edit_of_an_existing_file(standin):
     assert sorted(set(new) - set(old)) == sorted(
         os.path.join(*p.split("/")) for p in ADDED)
     assert all(new[p] == data for p, data in old.items())
-    # the shipped cells keep the default family
-    bench = Bench(ROOT)
-    assert {bench.cell(w["name"])["family"]
-            for w in bench.doc["workloads"]} == {"alpine"}
+    # a shipped cell whose configuration names no family reads the
+    # default, alpine; a family a shipped configuration names loads
+    # from its own file and keeps the contract
+    assert check_shipped_families(ROOT)
+
+
+def test_a_shipped_cell_of_another_family_passes_every_cell_check(
+        tmp_path):
+    """A copy of the benchmark in which the stand-in family is a shipped
+    cell: its family file and configuration, its entry in
+    `BENCHMARK.json`, and its name added to the served metrics'
+    `workloads`. Every check that goes over the shipped cells passes."""
+    shutil.copytree(os.path.join(ROOT, "benchmark"), tmp_path / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    doc = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    doc["configs"].append({"name": "cs-standin", "source": "x",
+                           "file": "benchmark/configs/cs-standin.json",
+                           "reduced": [], "why": "x"})
+    doc["workloads"].append({"name": "cs-standin.sweep", "config":
+                             "cs-standin", "traffic": "sweep16",
+                             "chips": 1, "why": "x"})
+    for m in doc["end_to_end"] + doc["per_layer"]:
+        if "cs-alpine.sweep" in m.get("workloads", []):
+            m["workloads"].append("cs-standin.sweep")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(doc))
+    for rel, text in zip(ADDED[:2], [STANDIN, json.dumps(STANDIN_CONFIG)]):
+        (tmp_path / "benchmark" / rel).write_text(text)
+    root = str(tmp_path)
+    assert check_shipped_families(root) == \
+        check_shipped_families(ROOT) | {"standin"}
+    check_cells(root)
+    bench = Bench(root)
+    assert bench.cell("cs-standin.sweep")["family"] == "standin"
+    for kind in ("end_to_end", "per_layer"):
+        assert bench.metrics("cs-standin.sweep", kind) == \
+            bench.metrics("cs-alpine.sweep", kind)
 
 
 def test_the_new_family_builds_its_table(standin):
